@@ -173,8 +173,6 @@ def flop_calibration(kernel: str, validate: bool = True) -> float:
     try:
         comp = jax.jit(fn).lower(*args).compile()
         ca = comp.cost_analysis()
-        if isinstance(ca, (list, tuple)):      # jax-0.4 list-valued form
-            ca = ca[0] if ca else {}
         reported = float((ca or {}).get("flops", 0.0))
         if reported > 0:
             total = space.flops_interpret(shape, cfg)

@@ -4,12 +4,15 @@ One query token per sequence attends over a blocked KV cache — the rollout
 stage's HBM-bound hot loop (the paper's Observation 1: decode reads the
 whole cache + weights per token, so HBM bandwidth is the roof).
 
-Tiling: grid = (B, Hkv, nC).  Per step, one (block_c × D) KV tile streams
-HBM→VMEM; the G query heads of the group score against it on the MXU;
-fp32 (acc, m, l) accumulators live in VMEM scratch across the sequential
-cache dimension.  Ragged batches are handled by per-slot absolute positions
-(k_pos; empty slots carry −2^30) — the same convention as the ring-buffer
-caches in models/.
+Tiling: grid = (B, nC).  Per step, one (block_c × Hkv × D) KV tile
+streams HBM→VMEM with all of its heads (the block's last two dims are the
+cache's own ``(Hkv, D)``, as the TPU's block-shape rule asks); for each KV
+head the G query heads of its group score against it on the MXU; fp32
+(acc, m, l) accumulators, 2-D per head, live in VMEM scratch across the
+sequential cache dimension.  The query positions ride in as a scalar-
+prefetch operand.  Ragged batches are handled by per-slot absolute
+positions (k_pos; empty slots carry −2^30) — the same convention as the
+ring-buffer caches in models/.
 """
 from __future__ import annotations
 
@@ -27,8 +30,9 @@ NEG_INF = -1e30
 
 def _decode_kernel(qpos_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
                    acc_ref, m_ref, l_ref, *,
-                   scale: float, window: Optional[int], n_c: int):
-    ic = pl.program_id(2)
+                   scale: float, window: Optional[int], n_c: int, n_kv: int):
+    b = pl.program_id(0)
+    ic = pl.program_id(1)
 
     @pl.when(ic == 0)
     def _init():
@@ -36,34 +40,35 @@ def _decode_kernel(qpos_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale      # [G, D]
-    k = k_ref[0, :, 0].astype(jnp.float32)           # [bc, D]
-    v = v_ref[0, :, 0].astype(jnp.float32)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # [G, bc]
-
-    qpos = qpos_ref[0]                               # scalar (prefetch)
-    kpos = kpos_ref[0]                               # [bc]
+    qpos = qpos_ref[b]                               # scalar (prefetch)
+    kpos = kpos_ref[0]                               # [1, bc]
     ok = jnp.logical_and(kpos >= 0, kpos <= qpos)
     if window is not None:
         ok = jnp.logical_and(ok, kpos > qpos - window)
-    s = jnp.where(ok[None, :], s, NEG_INF)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    p = jnp.exp(s - m_new[:, None])
-    p = jnp.where((m_new == NEG_INF)[:, None], 0.0, p)
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1)
-    acc_ref[...] = (acc_ref[...] * corr[:, None]
-                    + jax.lax.dot(p.astype(v.dtype), v,
-                                  preferred_element_type=jnp.float32))
-    m_ref[...] = m_new
+    for h in range(n_kv):
+        q = q_ref[0, h].astype(jnp.float32) * scale          # [G, D]
+        k = k_ref[0, :, h, :].astype(jnp.float32)            # [bc, D]
+        v = v_ref[0, :, h, :].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)  # [G, bc]
+        s = jnp.where(ok, s, NEG_INF)
+
+        m_prev = m_ref[h]                                     # [G, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        p = jnp.where(m_new == NEG_INF, 0.0, p)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[h] = (acc_ref[h] * corr
+                      + jax.lax.dot(p.astype(v.dtype), v,
+                                    preferred_element_type=jnp.float32))
+        m_ref[h] = m_new
 
     @pl.when(ic == n_c - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0, ...] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def flash_decode(
@@ -85,27 +90,29 @@ def flash_decode(
     n_c = C // block_c
 
     kernel = functools.partial(_decode_kernel, scale=scale, window=window,
-                               n_c=n_c)
-    grid = (B, Hkv, n_c)
+                               n_c=n_c, n_kv=Hkv)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,                       # q_pos
+        grid=(B, n_c),
+        in_specs=[
+            pl.BlockSpec((1, Hkv, G, D), lambda b, ic, qp: (b, 0, 0, 0)),
+            pl.BlockSpec((1, block_c, Hkv, D),
+                         lambda b, ic, qp: (b, ic, 0, 0)),
+            pl.BlockSpec((1, block_c, Hkv, D),
+                         lambda b, ic, qp: (b, ic, 0, 0)),
+            pl.BlockSpec((1, 1, block_c),
+                         lambda b, ic, qp: (b, 0, ic)),      # k_pos
+        ],
+        out_specs=pl.BlockSpec((1, Hkv, G, D), lambda b, ic, qp: (b, 0, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((Hkv, G, D), jnp.float32),
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),
+        ],
+    )
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, h, ic: (b,),
-                         memory_space=pltpu.SMEM),            # q_pos
-            pl.BlockSpec((1, 1, G, D), lambda b, h, ic: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_c, 1, D),
-                         lambda b, h, ic: (b, ic, h, 0)),
-            pl.BlockSpec((1, block_c, 1, D),
-                         lambda b, h, ic: (b, ic, h, 0)),
-            pl.BlockSpec((1, block_c), lambda b, h, ic: (b, ic)),  # k_pos
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, ic: (b, h, 0, 0)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((G, D), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-        ],
         interpret=interpret,
-    )(q_pos, q, k, v, k_pos)
+    )(q_pos, q, k, v, k_pos.reshape(B, 1, C))

@@ -1,7 +1,8 @@
 """Pallas TPU flash-attention forward kernel (causal / SWA / GQA).
 
 Tiling: grid = (B, H, nQ, nK); per grid step one (block_q × block_k) score
-tile lives in VMEM, with fp32 running (acc, m, l) accumulators carried in
+tile lives in VMEM, with fp32 running (acc, m, l) accumulators (m and l as
+[block_q, 1] columns: Mosaic refuses 1-D row statistics) carried in
 VMEM scratch across the sequential nK dimension (TPU grids iterate the
 minor-most axis innermost, so scratch carries are the canonical flash
 pattern).  Block sizes default to 128×128 — MXU-aligned (the MXU consumes
@@ -68,15 +69,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
             ok = jnp.logical_and(ok, kpos > qpos - window)
         s = jnp.where(ok, s, NEG_INF)
 
-        m_prev = m_ref[...]
+        m_prev = m_ref[...]                                  # [bq, 1]
         l_prev = l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         # rows where everything is masked: exp(NEG-NEG)=1 ⇒ zero them
-        p = jnp.where((m_new == NEG_INF)[:, None], 0.0, p)
+        p = jnp.where(m_new == NEG_INF, 0.0, p)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_prev * corr + jnp.sum(p, axis=1)
-        acc_ref[...] = (acc_ref[...] * corr[:, None]
+        l_ref[...] = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = (acc_ref[...] * corr
                         + jax.lax.dot(p.astype(v.dtype), v,
                                       preferred_element_type=jnp.float32))
         m_ref[...] = m_new
@@ -85,7 +86,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     def _finalize():
         l = l_ref[...]
         safe = jnp.maximum(l, 1e-30)
-        o_ref[0, 0, ...] = (acc_ref[...] / safe[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0, ...] = (acc_ref[...] / safe).astype(o_ref.dtype)
 
 
 def flash_attention_fwd(
@@ -131,8 +132,8 @@ def flash_attention_fwd(
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, D), jnp.float32),   # acc
-            pltpu.VMEM((block_q,), jnp.float32),     # m
-            pltpu.VMEM((block_q,), jnp.float32),     # l
+            pltpu.VMEM((block_q, 1), jnp.float32),   # m
+            pltpu.VMEM((block_q, 1), jnp.float32),   # l
         ],
         interpret=interpret,
     )(q, k, v)

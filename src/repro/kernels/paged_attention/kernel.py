@@ -7,15 +7,17 @@ kernel streams exactly the sequence's own pages HBM→VMEM — the serving
 hot loop stays HBM-bound on useful bytes (paper Observation 1) instead of
 on a right-padded dense cache.
 
-Tiling: grid = (B, Hkv, maxp).  Block tables and lengths ride in as
+Tiling: grid = (B, maxp).  Block tables and lengths ride in as
 scalar-prefetch operands so the KV BlockSpec index maps *gather*: step
-(b, h, ip) DMAs physical page ``block_tables[b, ip]``.  fp32 (acc, m, l)
-accumulators live in VMEM scratch across the sequential page axis; pages
-wholly past the sequence length are skipped with ``pl.when`` (their DMA
-still lands, so unused table entries must point at a valid page — the
-pool reserves page 0 as that null sink).  The tail page is masked by
-logical slot position, mirroring the ragged-batch convention of
-``kernels/decode_attention``.
+(b, ip) DMAs physical page ``block_tables[b, ip]`` with all of its Hkv
+heads in one block, whose last two dims ``(Hkv, D)`` are the pool's own,
+as the TPU's block-shape rule asks; the kernel loops over the heads.  fp32
+(acc, m, l) accumulators, 2-D per head, live in VMEM scratch across the
+sequential page axis; pages wholly past the sequence length are skipped
+with ``pl.when`` (their DMA still lands, so unused table entries must
+point at a valid page — the pool reserves page 0 as that null sink).  The
+tail page is masked by logical slot position, mirroring the ragged-batch
+convention of ``kernels/decode_attention``.
 """
 from __future__ import annotations
 
@@ -33,9 +35,10 @@ NEG_INF = -1e30
 
 def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                   acc_ref, m_ref, l_ref, *,
-                  scale: float, window: Optional[int], page: int, maxp: int):
+                  scale: float, window: Optional[int], page: int, maxp: int,
+                  n_kv: int):
     b = pl.program_id(0)
-    ip = pl.program_id(2)
+    ip = pl.program_id(1)
 
     @pl.when(ip == 0)
     def _init():
@@ -48,33 +51,33 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(start < length)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale  # [G, D]
-        k = k_ref[0, :, 0].astype(jnp.float32)       # [page, D]
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # [G, page]
-
         slot = start + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
         ok = slot < length
         if window is not None:
             ok = jnp.logical_and(ok, slot > (length - 1) - window)
-        s = jnp.where(ok, s, NEG_INF)                # ok: [1, page] broadcasts
+        for h in range(n_kv):
+            q = q_ref[0, h].astype(jnp.float32) * scale       # [G, D]
+            k = k_ref[0, :, h, :].astype(jnp.float32)         # [page, D]
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(ok, s, NEG_INF)            # ok: [1, page] broadcasts
 
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where((m_new == NEG_INF)[:, None], 0.0, p)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1)
-        acc_ref[...] = (acc_ref[...] * corr[:, None]
-                        + jax.lax.dot(p.astype(v.dtype), v,
-                                      preferred_element_type=jnp.float32))
-        m_ref[...] = m_new
+            m_prev = m_ref[h]                                  # [G, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            p = jnp.where(m_new == NEG_INF, 0.0, p)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[h] = (acc_ref[h] * corr
+                          + jax.lax.dot(p.astype(v.dtype), v,
+                                        preferred_element_type=jnp.float32))
+            m_ref[h] = m_new
 
     @pl.when(ip == maxp - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0, ...] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def paged_flash_decode(
@@ -94,23 +97,23 @@ def paged_flash_decode(
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
 
     kernel = functools.partial(_paged_kernel, scale=scale, window=window,
-                               page=page, maxp=maxp)
+                               page=page, maxp=maxp, n_kv=Hkv)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                       # block_tables, lengths
-        grid=(B, Hkv, maxp),
+        grid=(B, maxp),
         in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, ip, bt, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, page, 1, D),
-                         lambda b, h, ip, bt, ln: (bt[b, ip], 0, h, 0)),
-            pl.BlockSpec((1, page, 1, D),
-                         lambda b, h, ip, bt, ln: (bt[b, ip], 0, h, 0)),
+            pl.BlockSpec((1, Hkv, G, D), lambda b, ip, bt, ln: (b, 0, 0, 0)),
+            pl.BlockSpec((1, page, Hkv, D),
+                         lambda b, ip, bt, ln: (bt[b, ip], 0, 0, 0)),
+            pl.BlockSpec((1, page, Hkv, D),
+                         lambda b, ip, bt, ln: (bt[b, ip], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, D),
-                               lambda b, h, ip, bt, ln: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, Hkv, G, D),
+                               lambda b, ip, bt, ln: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((G, D), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
+            pltpu.VMEM((Hkv, G, D), jnp.float32),
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),
         ],
     )
     return pl.pallas_call(

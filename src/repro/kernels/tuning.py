@@ -46,11 +46,8 @@ def current_device_type() -> Optional[str]:
     """Profile name of the local accelerator, or None when unknown (CPU)."""
     if _DEVICE_TYPE_OVERRIDE is not None:
         return _DEVICE_TYPE_OVERRIDE
-    try:
-        import jax
-        kind = jax.devices()[0].device_kind
-    except Exception:                                     # pragma: no cover
-        return None
+    import jax
+    kind = jax.devices()[0].device_kind
     if kind in _DEVICE_KIND_TO_PROFILE:
         return _DEVICE_KIND_TO_PROFILE[kind]
     for prefix, name in _DEVICE_KIND_TO_PROFILE.items():

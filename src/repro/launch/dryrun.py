@@ -1,11 +1,15 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: AOT lower + compile every (arch × shape × mesh) cell.
 
-The two lines above MUST stay the very first statements — jax locks the
+The lines above MUST stay the very first statements — jax locks the
 device count at first init, and this module needs 512 placeholder host
 devices to build the production meshes.  Never set that flag globally.
+It is pinned to the CPU so that on a machine with an accelerator neither
+it nor its ``--all`` children (which inherit the environment) take the
+chip.
 
 Per cell this:
   1. builds the full-size ModelConfig,
@@ -235,8 +239,6 @@ def run_cell(arch: str, shape_name: str, mesh_name: str,
     def _cost_of(comp):
         try:
             ca = comp.cost_analysis()
-            if isinstance(ca, (list, tuple)):
-                ca = ca[0]
             return dict(ca) if ca else {}
         except Exception as e:                             # pragma: no cover
             log.info(f"cost_analysis unavailable: {e}", error=str(e))

@@ -131,8 +131,10 @@ def calibrate_cost_analysis() -> float:
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    from repro.launch.mesh import make_mesh
+
     n = len(jax.devices())
-    mesh = jax.make_mesh((n,), ("x",))
+    mesh = make_mesh((n,), ("x",))
     dim = 512
     true_flops = 2 * dim ** 3
 

@@ -35,6 +35,7 @@ import time
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import use_compile_cache
 from repro.obs import log
 
 
@@ -65,6 +66,7 @@ def main() -> None:
                          "--metrics PATH)")
     args = ap.parse_args()
     log.configure(args)
+    use_compile_cache()
 
     from repro.configs import get_config, get_smoke_config
     from repro.data.tasks import MathTaskGenerator, Tokenizer
@@ -74,7 +76,7 @@ def main() -> None:
 
     tok = Tokenizer()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    cfg = cfg.replace(vocab=tok.vocab_size, dtype="float32", remat=False)
+    cfg = cfg.replace(vocab=tok.vocab_size)
     model = get_model(cfg)
     params = model.init(jax.random.PRNGKey(args.seed), cfg)
     store = WeightStore()

@@ -21,6 +21,7 @@ from pathlib import Path
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import use_compile_cache
 from repro.obs import log
 
 
@@ -59,6 +60,7 @@ def main() -> None:
                          "--metrics PATH)")
     args = ap.parse_args()
     log.configure(args)
+    use_compile_cache()
 
     from repro.configs import get_config, get_smoke_config
     from repro.core.staleness import StalenessConfig
@@ -69,7 +71,7 @@ def main() -> None:
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     tok = Tokenizer()
-    cfg = cfg.replace(vocab=tok.vocab_size, dtype="float32", remat=False)
+    cfg = cfg.replace(vocab=tok.vocab_size)
 
     if args.schedule:
         from repro.core.scheduler import schedule

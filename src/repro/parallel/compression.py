@@ -20,12 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:                                     # jax ≥ 0.6: top-level export,
-    from jax import shard_map            # replication check kwarg=check_vma
-    _SHMAP_CHECK_KWARG = "check_vma"
-except ImportError:                      # jax 0.4.x: experimental module,
-    from jax.experimental.shard_map import shard_map  # kwarg=check_rep
-    _SHMAP_CHECK_KWARG = "check_rep"
+from jax import shard_map
 
 
 def _quantize(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -65,7 +60,7 @@ def make_compressed_allreduce(mesh: Mesh, axis: str = "data"):
         spec = P(*([None] * g.ndim))
         return shard_map(body, mesh=mesh, in_specs=(spec, spec),
                          out_specs=(spec, spec),
-                         **{_SHMAP_CHECK_KWARG: False})(g, r)
+                         check_vma=False)(g, r)
 
     def allreduce(grads: Any, residual: Any) -> Tuple[Any, Any]:
         out = jax.tree_util.tree_map(one, grads, residual)

@@ -17,7 +17,8 @@ COMPRESS_TEST = textwrap.dedent("""
     import jax, jax.numpy as jnp
     from repro.parallel.compression import (init_residual,
                                             make_compressed_allreduce)
-    mesh = jax.make_mesh((4,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4,), ("data",))
     f = make_compressed_allreduce(mesh, "data")
     key = jax.random.PRNGKey(0)
     grads = {"w": jax.random.normal(key, (32, 32))}

@@ -25,12 +25,12 @@ def test_param_specs_cover_every_leaf(arch):
     """Every parameter gets a spec of matching rank; model-axis entries only
     on dims that exist."""
     from repro.parallel import sharding as shd
-    from repro.launch.mesh import make_host_mesh
+    from repro.launch.mesh import make_mesh
     cfg = get_smoke_config(arch)
     model = get_model(cfg)
     shapes = jax.eval_shape(lambda k: model.init(k, cfg),
                             jax.random.PRNGKey(0))
-    mesh = make_host_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     specs = shd.param_pspecs(shapes, cfg, mesh)
     leaves_s, _ = jax.tree_util.tree_flatten(
         specs, is_leaf=lambda x: isinstance(x, P))
@@ -43,8 +43,8 @@ def test_param_specs_cover_every_leaf(arch):
 
 def test_zero_extend_picks_divisible_dim():
     from repro.parallel.sharding import zero_extend
-    from repro.launch.mesh import make_host_mesh
-    mesh = make_host_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     # data axis size 1 → everything divides; largest unsharded dim chosen
     spec = zero_extend(P(None, "model"), (64, 128), mesh)
     assert spec[0] == ("data",) or spec[0] == "data" or spec == \
@@ -64,9 +64,10 @@ MINI_DRYRUN = textwrap.dedent("""
     from repro.parallel import sharding as shd
     from repro.rl.grpo import make_train_step
     from repro.launch.roofline import parse_collectives
+    from repro.launch.mesh import make_mesh
 
     cfg = get_smoke_config("{arch}").replace(dtype="float32")
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     model = get_model(cfg)
     params_shape = jax.eval_shape(lambda k: model.init(k, cfg),
                                   jax.random.PRNGKey(0))
@@ -95,8 +96,6 @@ MINI_DRYRUN = textwrap.dedent("""
         compiled = lowered.compile()
     stats = parse_collectives(compiled.as_text())
     ca = compiled.cost_analysis() or dict()
-    if isinstance(ca, (list, tuple)):      # jax 0.4.x returns [dict]
-        ca = ca[0] if ca else dict()
     print(json.dumps(dict(ok=True,
                           collectives=sum(stats.counts.values()),
                           flops=float(ca.get("flops", 0)))))
