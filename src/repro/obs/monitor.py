@@ -271,8 +271,9 @@ class HealthMonitor:
                        t: float, dur: float, args: Dict) -> None:
         """Tracer sink (install with ``tracer.add_sink``): routes the
         repo's span conventions — ``replica``/``r{i}`` or
-        ``{job}/r{i}`` tracks carry ``tokens``; ``stage`` tracks are
-        pipeline stages — into the direct feeds above."""
+        ``{job}/r{i}`` tracks carry ``tokens``; ``stage`` tracks, and the
+        engine's ``decode``/``prefill`` phases, are pipeline stages —
+        into the direct feeds above."""
         if ph != "X":
             return
         if group == "replica":
@@ -286,7 +287,8 @@ class HealthMonitor:
                 if tokens is not None:
                     self.on_gen_span(job or "job", idx, t, dur,
                                      float(tokens))
-        elif group == "stage":
+        elif group == "stage" or (group == "engine"
+                                  and track in ("decode", "prefill")):
             self.on_stage_span(track, t, dur)
 
     # ------------------------------------------------- registry consumption

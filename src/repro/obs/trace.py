@@ -14,6 +14,16 @@ Design constraints (ISSUE 8):
     straight from their event loop; runtime components (engine,
     trainer, scheduler) pass :meth:`Tracer.now`, wall-clock seconds
     since tracer creation.  Never mix the two in one tracer.
+  * **Device time comes from the profiler, not from here** — a host
+    span around an asynchronous dispatch ends before the device work it
+    launched.  So the engine's phases are :func:`scope` spans: each is a
+    ``jax.profiler.TraceAnnotation`` named ``engine.<phase>``, which a
+    ``jax.profiler`` session records on the ``/host:CPU`` plane of its
+    ``.xplane.pb``, on the clock of the device's ``XLA Ops``.  Its times
+    are relative to the session's start, not to any system clock, so a
+    tracer cannot share that clock by reading one; where a tracer is
+    given, the scope records the same interval on it as well, on the
+    tracer's own timebase.
   * **Groups and tracks** — every event lives on a ``(group, track)``
     pair which export maps to a Chrome ``(pid, tid)`` with
     ``process_name`` / ``thread_name`` metadata, so Perfetto renders one
@@ -29,8 +39,11 @@ Design constraints (ISSUE 8):
                   ``{job}/r{i}``           time; Σdur == ledger busy)
       sim/pool    plan                     drain→commit swap windows
       scheduler   pool                     schedule_pool / replan_pool
-      engine      loop/decode/prefill/     PagedEngine (wall-clock)
-                  admission/weights
+      engine      step/admit/reserve/      PagedEngine (wall-clock):
+                  decode/weights/inputs/   ``scope`` spans, and the
+                  dispatch/sample/wait/    admission/weights instants
+                  bookkeep/prefill/        and the pages counter
+                  finish
       jobs        ``{job}``                ControlPlane admission
       ==========  =======================  =============================
 """
@@ -38,7 +51,10 @@ from __future__ import annotations
 
 import json
 import time
+from contextlib import contextmanager
 from typing import Any, Dict, Iterator, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 
 class TraceError(RuntimeError):
@@ -187,3 +203,24 @@ class Tracer:
         with open(path, "w") as f:
             json.dump(self.to_chrome(), f, default=str)
         return path
+
+
+def scope(tracer: Optional[Tracer], name: str, **args: Any):
+    """One phase of the serving engine, as a context manager.  Always a
+    profiler annotation ``engine.<name>`` (a no-op outside a profiler
+    session); with a ``tracer``, also an ``X`` span on its
+    ``engine/<name>`` track that carries ``args``."""
+    if tracer is None:
+        return TraceAnnotation(f"engine.{name}")
+    return _traced_scope(tracer, name, args)
+
+
+@contextmanager
+def _traced_scope(tracer: Tracer, name: str,
+                  args: Dict[str, Any]) -> Iterator[None]:
+    with TraceAnnotation(f"engine.{name}"):
+        t0 = tracer.now()
+        try:
+            yield
+        finally:
+            tracer.span("engine", name, name, t0, tracer.now() - t0, **args)

@@ -83,7 +83,7 @@ import numpy as np
 from repro.data.tasks import MathTask
 from repro.models.api import ModelConfig
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import Tracer
+from repro.obs.trace import Tracer, scope
 from repro.rl.buffer import Rollout
 from repro.rl.rollout import GenConfig
 from repro.rl.weight_sync import WeightStore
@@ -122,6 +122,8 @@ class EngineStats:
     forks: int = 0                     # sibling sequences forked
     cow_copies: int = 0                # divergent-write page copies
     bt_uploads: int = 0                # host→device block-table uploads
+    host_arg_bytes: int = 0            # bytes of host (non-device) arguments
+                                       # passed to the jitted decode/prefill
     wall_time_s: float = 0.0
     page_occ_sum: float = 0.0
     pool_util_sum: float = 0.0
@@ -185,7 +187,8 @@ class EngineStats:
                      "prefill_tokens_shared", "radix_hit_tokens",
                      "tokens_generated", "preempted_slot_steps",
                      "weight_swaps", "admissions", "preemptions",
-                     "completed", "forks", "cow_copies", "bt_uploads"):
+                     "completed", "forks", "cow_copies", "bt_uploads",
+                     "host_arg_bytes"):
             reg.counter(f"engine/{name}").inc(getattr(self, name))
         reg.gauge("engine/max_slots").set(self.max_slots)
         reg.gauge("engine/wall_time_s").set(self.wall_time_s)
@@ -242,6 +245,12 @@ class _Request:
         return bool(self.tokens) and len(self.tokens) >= self.max_new
 
 
+def _host_bytes(*leaves: Any) -> int:
+    """Bytes of the leaves that are not ``jax.Array``s: what a jitted call
+    copies from the host to the device for them."""
+    return sum(x.nbytes for x in leaves if not isinstance(x, jax.Array))
+
+
 def _nucleus_filter(logits: jax.Array, top_p: float) -> jax.Array:
     """Mask logits outside the smallest token set whose cumulative
     probability reaches ``top_p`` (nucleus sampling).  The top-1 token is
@@ -259,24 +268,23 @@ class PagedEngine:
     def __init__(self, cfg: ModelConfig, store: WeightStore,
                  gen: Optional[GenConfig] = None,
                  serve: Optional[ServeConfig] = None, rng_seed: int = 0,
-                 tracer: Optional[Tracer] = None, monitor=None):
+                 tracer: Optional[Tracer] = None):
         if cfg.family not in ("dense", "vlm"):
             raise ValueError(
                 f"paged serving covers the dense-transformer family; "
                 f"{cfg.family!r} models use the static RolloutEngine")
         self.cfg = cfg
         self.store = store
-        # wall-clock tracer (repro.obs); None = zero-cost no-op — the
-        # token stream is bit-identical either way (tests/test_obs.py)
+        # wall-clock tracer (repro.obs); None records no spans — the
+        # token stream is bit-identical either way (tests/test_obs.py).
+        # The phases are profiler annotations (``scope``) in both cases.
         self._tracer = tracer
-        # wall-clock health monitor (repro.obs.HealthMonitor): decode /
-        # prefill stage spans feed its bubble detector.  None = no-op;
-        # tests/test_monitor.py asserts token identity off vs on.
-        self._monitor = monitor
         self.gen = gen or GenConfig()
         self.serve = serve or ServeConfig()
         self._rng = jax.random.PRNGKey(rng_seed)
         self._params, self._version = store.fetch(dtype=cfg.jdtype)
+        self._params_host_bytes = _host_bytes(
+            *jax.tree_util.tree_leaves(self._params))
         self.kv = PagedKVCache(cfg, max_slots=self.serve.max_slots,
                                max_len=self.serve.max_len,
                                num_pages=self.serve.num_pages,
@@ -288,22 +296,20 @@ class PagedEngine:
         self._active: Dict[int, _Request] = {}       # slot → request
         self._done: List[_Request] = []
         self._bt_dev: Optional[jax.Array] = None     # cached device table
-        self._decode = jax.jit(
-            lambda p, kp, vp, bt, tok, pos, act:
-            paged_decode_step(p, self.cfg, kp, vp, bt, tok, pos, act))
-        self._prefill = jax.jit(
-            lambda p, kp, vp, row, toks, p0:
-            paged_prefill_chunk(p, self.cfg, kp, vp, row, toks, p0))
+        # named programs (``jit_paged_decode_step`` in a profile); the
+        # config is a static argument
+        self._decode = jax.jit(paged_decode_step, static_argnums=1)
+        self._prefill = jax.jit(paged_prefill_chunk, static_argnums=1)
 
     # ---------------------------------------------------------------- utils
     def _split(self):
         self._rng, k = jax.random.split(self._rng)
         return k
 
-    def _sample(self, logits: jax.Array, key) -> Tuple[np.ndarray, np.ndarray]:
-        """logits [..., padded_vocab] → (token ids, chosen logps), using the
-        engine-wide defaults — the batched fast path when no request in the
-        batch overrides its sampling params."""
+    def _sample(self, logits: jax.Array, key) -> Tuple[jax.Array, jax.Array]:
+        """logits [..., padded_vocab] → (token ids, chosen logps) on the
+        device, using the engine-wide defaults — the batched fast path when
+        no request in the batch overrides its sampling params."""
         logits = logits[..., :self.cfg.vocab].astype(jnp.float32)
         if self.gen.greedy:
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -312,14 +318,15 @@ class PagedEngine:
                 key, logits / self.gen.temperature, axis=-1).astype(jnp.int32)
         logp = jnp.take_along_axis(jax.nn.log_softmax(logits, axis=-1),
                                    tok[..., None], axis=-1)[..., 0]
-        return np.asarray(tok), np.asarray(logp)
+        return tok, logp
 
     def _sample_req(self, logits: jax.Array, key,
-                    req: "_Request") -> Tuple[int, float]:
-        """Single-row sample honoring ``req``'s own temperature / top_p /
-        greedy.  With engine-default params this computes exactly what
-        ``_sample`` would for the same key, so default requests stay
-        token-identical through either path."""
+                    req: "_Request") -> Tuple[jax.Array, jax.Array]:
+        """Single-row sample (token id, logp) on the device, honoring
+        ``req``'s own temperature / top_p / greedy.  With engine-default
+        params this computes exactly what ``_sample`` would for the same
+        key, so default requests stay token-identical through either
+        path."""
         logits = logits[..., :self.cfg.vocab].astype(jnp.float32)
         if req.greedy:
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -331,7 +338,7 @@ class PagedEngine:
                                          axis=-1).astype(jnp.int32)
         logp = jnp.take_along_axis(jax.nn.log_softmax(logits, axis=-1),
                                    tok[..., None], axis=-1)[..., 0]
-        return int(np.asarray(tok)), float(np.asarray(logp))
+        return tok, logp
 
     def _default_params(self, req: "_Request") -> bool:
         return (req.temperature == self.gen.temperature
@@ -342,6 +349,8 @@ class PagedEngine:
         if self.store.version > self._version:
             self._params, self._version = self.store.fetch(
                 dtype=self.cfg.jdtype)
+            self._params_host_bytes = _host_bytes(
+                *jax.tree_util.tree_leaves(self._params))
             self.stats.weight_swaps += 1
             if self._tracer is not None:
                 self._tracer.instant("engine", "weights", "swap",
@@ -657,20 +666,19 @@ class PagedEngine:
             return False
         now = time.time()
         tr = self._tracer
-        if tr is not None:
-            tr.begin("engine", "loop", "step", tr.now(),
-                     queued=len(self._queue), active=len(self._active))
-        self._admit(now)
-        try:
-            return self._step_body(now)
-        finally:
-            # wall time accrues per step so the stepwise submit/step/collect
-            # path reports real lifetime throughput, not 0
-            self.stats.wall_time_s += time.time() - now
-            if tr is not None:
-                tr.end("engine", "loop", tr.now())
+        with scope(tr, "step", queued=len(self._queue),
+                   active=len(self._active)):
+            with scope(tr, "admit"):
+                self._admit(now)
+            try:
+                return self._step_body(now)
+            finally:
+                # wall time accrues per step so the stepwise submit/step/
+                # collect path reports real lifetime throughput, not 0
+                self.stats.wall_time_s += time.time() - now
 
     def _step_body(self, now: float) -> bool:
+        tr = self._tracer
         decode_slots = sorted(s for s, r in self._active.items()
                               if r.state == "DECODE")
         budget = (self.serve.token_budget
@@ -680,23 +688,26 @@ class PagedEngine:
             # every sequence is about to write one token: COW-privatize the
             # target page and grow the table to cover it; preempt
             # youngest-first until the pool covers the rest
-            while True:
-                lacking = [
-                    s for s in decode_slots
-                    if not (self.kv.writable(s, self._active[s].written)
-                            and self.kv.ensure(s, self._active[s].written + 1))
-                ]
-                if not lacking:
-                    break
-                # idle radix leaves are cheaper to reclaim than a live
-                # sequence's work: evict before preempting
-                if self._radix_evict(len(lacking)):
-                    continue
-                if not self._preempt_youngest():
-                    raise RuntimeError(
-                        "page pool exhausted with a single sequence active "
-                        "— num_pages cannot cover max_len")
-                decode_slots = [s for s in decode_slots if s in self._active]
+            with scope(tr, "reserve"):
+                while True:
+                    lacking = [
+                        s for s in decode_slots
+                        if not (self.kv.writable(s, self._active[s].written)
+                                and self.kv.ensure(
+                                    s, self._active[s].written + 1))
+                    ]
+                    if not lacking:
+                        break
+                    # idle radix leaves are cheaper to reclaim than a live
+                    # sequence's work: evict before preempting
+                    if self._radix_evict(len(lacking)):
+                        continue
+                    if not self._preempt_youngest():
+                        raise RuntimeError(
+                            "page pool exhausted with a single sequence "
+                            "active — num_pages cannot cover max_len")
+                    decode_slots = [s for s in decode_slots
+                                    if s in self._active]
             if decode_slots:
                 self._decode_batch(decode_slots, now)
                 budget -= len(decode_slots)
@@ -707,90 +718,96 @@ class PagedEngine:
                 break
             budget -= self._prefill_one(self._active[slot])
 
-        for slot in sorted(self._active):
-            req = self._active[slot]
-            if req.state == "DECODE" and req.finished:
-                self._finish(req, now)
+        with scope(tr, "finish"):
+            for slot in sorted(self._active):
+                req = self._active[slot]
+                if req.state == "DECODE" and req.finished:
+                    self._finish(req, now)
         self.stats.cow_copies = self.kv.cow_copies
         return True
 
     def _decode_batch(self, slots: List[int], now: float) -> None:
         tr = self._tracer
-        t0 = tr.now() if tr is not None else 0.0
-        mon = self._monitor
-        m0 = mon.now() if mon is not None else 0.0
-        if self.stats.decode_steps % max(self.gen.segment, 1) == 0:
-            self._maybe_swap_weights()
-        S = self.serve.max_slots
-        token = np.zeros((S,), np.int32)
-        pos = np.zeros((S,), np.int32)
-        active = np.zeros((S,), np.int32)
-        for s in slots:
-            r = self._active[s]
-            token[s] = r.tokens[-1]
-            pos[s] = r.written                       # slot the token lands in
-            active[s] = 1
-        # the device block table is cached: re-upload only when the
-        # allocator mutated the host copy; inactive-slot masking happens
-        # inside the jitted step (null-page routing), not by editing rows
-        if self.kv.dirty or self._bt_dev is None:
-            self._bt_dev = jnp.asarray(self.kv.block_tables)
-            self.kv.dirty = False
-            self.stats.bt_uploads += 1
-        logits, nk, nv = self._decode(
-            self._params, self.kv.k_pages, self.kv.v_pages,
-            self._bt_dev, jnp.asarray(token), jnp.asarray(pos),
-            jnp.asarray(active))
-        self.kv.k_pages, self.kv.v_pages = nk, nv
-        if all(self._default_params(self._active[s]) for s in slots):
-            arr_toks, arr_logps = self._sample(logits, self._split())
-            toks = {s: int(arr_toks[s]) for s in slots}
-            logps = {s: float(arr_logps[s]) for s in slots}
-        else:
-            # at least one row overrides its sampling params: sample rows
-            # individually (slow path; the default-config stream above is
-            # bit-identical to the pre-override engine)
-            toks, logps = {}, {}
-            for s in slots:
-                toks[s], logps[s] = self._sample_req(
-                    logits[s], self._split(), self._active[s])
-        for s in slots:
-            r = self._active[s]
-            r.tokens.append(toks[s])
-            r.logps.append(logps[s])
-            self.kv.seq_lens[s] = r.written
-            self.stats.tokens_generated += 1
-            if r.tokens[-1] == self.gen.eos_id:
-                r.max_new = len(r.tokens)               # stop this row
-        self.stats.decode_steps += 1
-        self.stats.decode_slot_steps += len(slots)
-        occ = self.kv.occupancy()
-        self.stats.page_occ_sum += occ["page_occupancy"]
-        self.stats.pool_util_sum += occ["pool_util"]
-        self.stats.shared_frac_sum += occ["shared_frac"]
-        self.stats.occ_samples += 1
-        if tr is not None:
-            tr.span("engine", "decode", "decode_step", t0, tr.now() - t0,
-                    slots=len(slots))
-            tr.counter("engine", "pages", tr.now(),
-                       free=self.kv.free_pages,
-                       occupancy=occ["page_occupancy"])
-        if mon is not None:
-            mon.on_stage_span("decode", m0, mon.now() - m0)
+        with scope(tr, "decode", slots=len(slots)):
+            if self.stats.decode_steps % max(self.gen.segment, 1) == 0:
+                with scope(tr, "weights"):
+                    self._maybe_swap_weights()
+            with scope(tr, "inputs"):
+                S = self.serve.max_slots
+                token = np.zeros((S,), np.int32)
+                pos = np.zeros((S,), np.int32)
+                active = np.zeros((S,), np.int32)
+                for s in slots:
+                    r = self._active[s]
+                    token[s] = r.tokens[-1]
+                    pos[s] = r.written               # slot the token lands in
+                    active[s] = 1
+                # the device block table is cached: re-upload only when the
+                # allocator mutated the host copy; inactive-slot masking
+                # happens inside the jitted step (null-page routing), not by
+                # editing rows
+                if self.kv.dirty or self._bt_dev is None:
+                    self._bt_dev = jnp.asarray(self.kv.block_tables)
+                    self.kv.dirty = False
+                    self.stats.bt_uploads += 1
+                self.stats.host_arg_bytes += (
+                    self._params_host_bytes + _host_bytes(token, pos, active))
+            with scope(tr, "dispatch"):
+                logits, nk, nv = self._decode(
+                    self._params, self.cfg, self.kv.k_pages, self.kv.v_pages,
+                    self._bt_dev, token, pos, active)
+            self.kv.k_pages, self.kv.v_pages = nk, nv
+            batched = all(self._default_params(self._active[s])
+                          for s in slots)
+            with scope(tr, "sample"):
+                if batched:
+                    sampled = self._sample(logits, self._split())
+                else:
+                    # at least one row overrides its sampling params:
+                    # sample rows individually (slow path; the default-
+                    # config stream above is bit-identical to the
+                    # pre-override engine)
+                    sampled = [self._sample_req(logits[s], self._split(),
+                                                self._active[s])
+                               for s in slots]
+            with scope(tr, "wait"):
+                sampled = jax.device_get(sampled)
+            with scope(tr, "bookkeep"):
+                if batched:
+                    toks, logps = sampled
+                    sampled = [(toks[s], logps[s]) for s in slots]
+                for s, (tok, logp) in zip(slots, sampled):
+                    r = self._active[s]
+                    r.tokens.append(int(tok))
+                    r.logps.append(float(logp))
+                    self.kv.seq_lens[s] = r.written
+                    self.stats.tokens_generated += 1
+                    if r.tokens[-1] == self.gen.eos_id:
+                        r.max_new = len(r.tokens)           # stop this row
+                self.stats.decode_steps += 1
+                self.stats.decode_slot_steps += len(slots)
+                occ = self.kv.occupancy()
+                self.stats.page_occ_sum += occ["page_occupancy"]
+                self.stats.pool_util_sum += occ["pool_util"]
+                self.stats.shared_frac_sum += occ["shared_frac"]
+                self.stats.occ_samples += 1
+                if tr is not None:
+                    tr.counter("engine", "pages", tr.now(),
+                               free=self.kv.free_pages,
+                               occupancy=occ["page_occupancy"])
 
-    def _fork_siblings(self, leader: _Request, last_logits: jax.Array,
-                       now: float) -> None:
+    def _fork_siblings(self, leader: _Request,
+                       sampled: List[Tuple[int, float]]) -> None:
         """Leader's prefill just completed: alias each waiting sibling's
-        block table onto the leader's prompt pages and sample its own
-        first token from the shared prompt logits.  No prefill compute,
-        no K/V movement — divergence is handled page-locally by the COW
-        barrier when siblings start writing."""
-        for sib in list(leader.forks):
+        block table onto the leader's prompt pages and give it its own
+        first token, ``sampled`` from the shared prompt logits.  No
+        prefill compute, no K/V movement — divergence is handled
+        page-locally by the COW barrier when siblings start writing."""
+        for sib, (tok, logp) in zip(list(leader.forks), sampled):
             got = self.kv.fork_slot(leader.slot, leader.plen, child=sib.slot)
             assert got == sib.slot
-            tok, logp = self._sample_req(last_logits, self._split(), sib)
-            sib.tokens.append(tok)
-            sib.logps.append(logp)
+            sib.tokens.append(int(tok))
+            sib.logps.append(float(logp))
             sib.state = "DECODE"
             sib.parent = None
             sib.forked = True
@@ -807,42 +824,47 @@ class PagedEngine:
 
     def _prefill_one(self, req: _Request) -> int:
         tr = self._tracer
-        t0 = tr.now() if tr is not None else 0.0
-        mon = self._monitor
-        m0 = mon.now() if mon is not None else 0.0
         chunk = self.serve.prefill_chunk
         n = min(chunk, req.plen - req.prefill_done)
-        toks = np.zeros((chunk,), np.int32)
-        toks[:n] = req.prompt[req.prefill_done:req.prefill_done + n]
-        # pad rows write past the prompt: beyond the allocated pages they
-        # land in the null page, inside them they hit slots this sequence
-        # overwrites at exactly those positions later, and every read masks
-        # by current length — unobservable either way
-        ok = self.kv.ensure(req.slot, req.plen)
-        assert ok, "admission reserved these"
-        logits, nk, nv = self._prefill(
-            self._params, self.kv.k_pages, self.kv.v_pages,
-            jnp.asarray(self.kv.block_tables[req.slot]),
-            jnp.asarray(toks), jnp.int32(req.prefill_done))
-        self.kv.k_pages, self.kv.v_pages = nk, nv
-        req.prefill_done += n
-        self.stats.prefill_tokens += n
-        if req.prefill_done >= req.plen:
-            first, logp = self._sample_req(logits[n - 1], self._split(), req)
-            req.tokens.append(first)
-            req.logps.append(logp)
-            req.state = "DECODE"
-            self.kv.seq_lens[req.slot] = req.plen
-            self.stats.tokens_generated += 1
-            if req.tokens[-1] == self.gen.eos_id:
-                req.max_new = 1                       # EOS straight away
-            if req.forks:
-                self._fork_siblings(req, logits[n - 1], time.time())
-        if tr is not None:
-            tr.span("engine", "prefill", "prefill_chunk", t0,
-                    tr.now() - t0, tokens=n, slot=req.slot)
-        if mon is not None:
-            mon.on_stage_span("prefill", m0, mon.now() - m0)
+        with scope(tr, "prefill", tokens=n, slot=req.slot):
+            toks = np.zeros((chunk,), np.int32)
+            toks[:n] = req.prompt[req.prefill_done:req.prefill_done + n]
+            # pad rows write past the prompt: beyond the allocated pages
+            # they land in the null page, inside them they hit slots this
+            # sequence overwrites at exactly those positions later, and
+            # every read masks by current length — unobservable either way
+            ok = self.kv.ensure(req.slot, req.plen)
+            assert ok, "admission reserved these"
+            row = self.kv.block_tables[req.slot]
+            p0 = np.int32(req.prefill_done)
+            self.stats.host_arg_bytes += (self._params_host_bytes
+                                          + _host_bytes(row, toks, p0))
+            with scope(tr, "dispatch"):
+                logits, nk, nv = self._prefill(
+                    self._params, self.cfg, self.kv.k_pages, self.kv.v_pages,
+                    row, toks, p0)
+            self.kv.k_pages, self.kv.v_pages = nk, nv
+            req.prefill_done += n
+            self.stats.prefill_tokens += n
+            if req.prefill_done >= req.plen:
+                # the leader's first token, then each waiting sibling's,
+                # all from the prompt's last logits
+                with scope(tr, "sample"):
+                    last = logits[n - 1]
+                    sampled = [self._sample_req(last, self._split(), r)
+                               for r in [req] + req.forks]
+                with scope(tr, "wait"):
+                    sampled = jax.device_get(sampled)
+                first, logp = sampled[0]
+                req.tokens.append(int(first))
+                req.logps.append(float(logp))
+                req.state = "DECODE"
+                self.kv.seq_lens[req.slot] = req.plen
+                self.stats.tokens_generated += 1
+                if req.tokens[-1] == self.gen.eos_id:
+                    req.max_new = 1                   # EOS straight away
+                if req.forks:
+                    self._fork_siblings(req, sampled[1:])
         return n
 
     # -------------------------------------------------------------- frontend

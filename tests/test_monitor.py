@@ -295,13 +295,18 @@ def test_paged_engine_tokens_bit_identical_with_monitor():
     sc = ServeConfig(max_slots=4, max_len=96)
 
     def run(monitor):
-        eng = PagedEngine(tiny, store, gen, sc, rng_seed=1, monitor=monitor)
+        # the engine's spans reach the monitor through a tracer sink
+        tracer = None
+        if monitor is not None:
+            tracer = Tracer()
+            tracer.add_sink(monitor.on_trace_event)
+        eng = PagedEngine(tiny, store, gen, sc, rng_seed=1, tracer=tracer)
         rollouts, _ = eng.generate(tasks)
         return [r.completion_ids for r in rollouts]
 
     mon = HealthMonitor()
     assert run(None) == run(mon)
-    assert mon._stages                      # decode/prefill spans did land
+    assert {"decode", "prefill"} <= set(mon._stages)   # spans did land
 
 
 # ================================================== e2e: monitor beats EWMA
