@@ -62,6 +62,9 @@ The device copy of the block table is *cached*: the allocator sets
 re-uploads only then (``stats.bt_uploads`` counts uploads); per-step
 slot masking moved into the jitted step (``active`` vector), so steady
 decode never re-streams the ``[max_slots, maxp]`` table to the device.
+The weights are kept on the device the same way: each version moves
+there once, when it is fetched (``stats.weight_upload_bytes`` counts
+those bytes), and every jitted call takes the resident tree.
 
 ``generate(tasks)`` matches the static engine's surface (rollouts +
 metrics) so launchers and trainers can swap engines; the stepwise
@@ -124,6 +127,8 @@ class EngineStats:
     bt_uploads: int = 0                # host→device block-table uploads
     host_arg_bytes: int = 0            # bytes of host (non-device) arguments
                                        # passed to the jitted decode/prefill
+    weight_upload_bytes: int = 0       # host bytes of the weight versions
+                                       # moved to the device (one per fetch)
     wall_time_s: float = 0.0
     page_occ_sum: float = 0.0
     pool_util_sum: float = 0.0
@@ -188,7 +193,7 @@ class EngineStats:
                      "tokens_generated", "preempted_slot_steps",
                      "weight_swaps", "admissions", "preemptions",
                      "completed", "forks", "cow_copies", "bt_uploads",
-                     "host_arg_bytes"):
+                     "host_arg_bytes", "weight_upload_bytes"):
             reg.counter(f"engine/{name}").inc(getattr(self, name))
         reg.gauge("engine/max_slots").set(self.max_slots)
         reg.gauge("engine/wall_time_s").set(self.wall_time_s)
@@ -282,14 +287,12 @@ class PagedEngine:
         self.gen = gen or GenConfig()
         self.serve = serve or ServeConfig()
         self._rng = jax.random.PRNGKey(rng_seed)
-        self._params, self._version = store.fetch(dtype=cfg.jdtype)
-        self._params_host_bytes = _host_bytes(
-            *jax.tree_util.tree_leaves(self._params))
+        self.stats = EngineStats(max_slots=self.serve.max_slots)
+        self._load_weights()
         self.kv = PagedKVCache(cfg, max_slots=self.serve.max_slots,
                                max_len=self.serve.max_len,
                                num_pages=self.serve.num_pages,
                                page_size=self.serve.page_size)
-        self.stats = EngineStats(max_slots=self.serve.max_slots)
         self.radix: Optional[RadixCache] = (RadixCache(self.kv)
                                             if self.serve.radix else None)
         self._queue: List[_Request] = []
@@ -345,12 +348,22 @@ class PagedEngine:
                 and req.top_p == getattr(self.gen, "top_p", 1.0)
                 and req.greedy == self.gen.greedy)
 
+    def _load_weights(self) -> None:
+        """Fetch the store's latest version and keep it on the device, so
+        the jitted calls take device weights: one host→device copy per
+        version, not one per call.  The old tree is dropped first, so one
+        version is resident at a time (an in-flight call holds its own
+        buffers until it completes).  Leaves that are already device
+        arrays (the quantized store's dequantized tree) do not move."""
+        self._params = None
+        params, self._version = self.store.fetch(dtype=self.cfg.jdtype)
+        self.stats.weight_upload_bytes += _host_bytes(
+            *jax.tree_util.tree_leaves(params))
+        self._params = jax.device_put(params)
+
     def _maybe_swap_weights(self) -> None:
         if self.store.version > self._version:
-            self._params, self._version = self.store.fetch(
-                dtype=self.cfg.jdtype)
-            self._params_host_bytes = _host_bytes(
-                *jax.tree_util.tree_leaves(self._params))
+            self._load_weights()
             self.stats.weight_swaps += 1
             if self._tracer is not None:
                 self._tracer.instant("engine", "weights", "swap",
@@ -750,8 +763,7 @@ class PagedEngine:
                     self._bt_dev = jnp.asarray(self.kv.block_tables)
                     self.kv.dirty = False
                     self.stats.bt_uploads += 1
-                self.stats.host_arg_bytes += (
-                    self._params_host_bytes + _host_bytes(token, pos, active))
+                self.stats.host_arg_bytes += _host_bytes(token, pos, active)
             with scope(tr, "dispatch"):
                 logits, nk, nv = self._decode(
                     self._params, self.cfg, self.kv.k_pages, self.kv.v_pages,
@@ -837,8 +849,7 @@ class PagedEngine:
             assert ok, "admission reserved these"
             row = self.kv.block_tables[req.slot]
             p0 = np.int32(req.prefill_done)
-            self.stats.host_arg_bytes += (self._params_host_bytes
-                                          + _host_bytes(row, toks, p0))
+            self.stats.host_arg_bytes += _host_bytes(row, toks, p0)
             with scope(tr, "dispatch"):
                 logits, nk, nv = self._prefill(
                     self._params, self.cfg, self.kv.k_pages, self.kv.v_pages,

@@ -5,9 +5,13 @@
     holds exactly one ``engine.dispatch`` and one ``engine.wait`` and lies
     inside an ``engine.step``; the tokens equal those of a run without
     the profiler.
-  * ``EngineStats.host_arg_bytes`` grows, per decode call, by the bytes of
-    the host parameter tree plus the host inputs; with parameters on the
-    device, by the inputs alone.
+  * The weights stay on the device: ``EngineStats.weight_upload_bytes``
+    reads the fetched tree's host bytes once, at construction (0 when the
+    store already hands out device arrays), and again at each weight swap;
+    ``EngineStats.host_arg_bytes`` grows, per decode call, by the host
+    inputs alone.
+  * After a swap the engine holds the new version as device arrays and
+    decodes with it.
   * ``obs.trace.scope`` records nested spans on a ``Tracer``.
 """
 import glob
@@ -100,28 +104,76 @@ def test_phase_scopes_nest_on_the_profiler_host_plane(per_row, tmp_path):
     assert sum(len(inside(p, "engine.wait")) for p in prefills) == 3
 
 
+def _tree_host_bytes(store):
+    params, _ = store.fetch(dtype=TINY.jdtype)
+    return sum(x.nbytes for x in jax.tree_util.tree_leaves(params)
+               if not isinstance(x, jax.Array))
+
+
+def _on_device(eng):
+    return all(isinstance(x, jax.Array)
+               for x in jax.tree_util.tree_leaves(eng._params))
+
+
 @pytest.mark.parametrize("quantize", [False, True],
                          ids=["host_params", "device_params"])
 def test_host_arg_bytes_per_decode_call(quantize):
     store = _store(quantize)
+    tree = _tree_host_bytes(store)
+    if quantize:
+        assert tree == 0                    # dequantized on the device
+    else:
+        leaves = jax.tree_util.tree_leaves(store.fetch()[0])
+        assert tree == sum(x.size * x.dtype.itemsize for x in leaves) > 0
     eng = PagedEngine(TINY, store, GEN, SERVE, rng_seed=1)
+    assert eng.stats.weight_upload_bytes == tree
+    assert _on_device(eng)
     eng.submit(MathTaskGenerator(seed=3).batch(3))
     while any(r.state != "DECODE" for r in eng._active.values()) \
             or eng._queue:
         eng.step()
-    params, _ = store.fetch(dtype=TINY.jdtype)
-    leaves = jax.tree_util.tree_leaves(params)
-    host = sum(x.nbytes for x in leaves if not isinstance(x, jax.Array))
-    if quantize:
-        assert host == 0                    # dequantized on the device
-    else:
-        assert host == sum(x.size * x.dtype.itemsize for x in leaves) > 0
     inputs = 3 * SERVE.max_slots * np.dtype(np.int32).itemsize
     for _ in range(3):
         b0, d0 = eng.stats.host_arg_bytes, eng.stats.decode_steps
         eng.step()
         assert eng.stats.decode_steps == d0 + 1
-        assert eng.stats.host_arg_bytes - b0 == host + inputs
+        assert eng.stats.host_arg_bytes - b0 == inputs
+        assert eng.stats.weight_upload_bytes == tree
+
+
+def test_weight_swap_keeps_the_new_version_on_the_device():
+    gen = GenConfig(max_new_tokens=12, segment=2, greedy=True, eos_id=-1)
+    tasks = MathTaskGenerator(seed=3).batch(3)
+    v2 = get_model(TINY).init(jax.random.PRNGKey(1), TINY)
+
+    def run(swap):
+        store = _store()
+        eng = PagedEngine(TINY, store, gen, SERVE, rng_seed=1)
+        eng.submit(tasks)
+        while eng.stats.decode_steps < 3:
+            assert eng.step()
+        if swap:
+            tree = _tree_host_bytes(store)
+            swaps, up = eng.stats.weight_swaps, eng.stats.weight_upload_bytes
+            store.publish(v2)
+            for _ in range(gen.segment):    # past the next segment boundary
+                eng.step()
+            assert eng.stats.weight_swaps == swaps + 1
+            assert eng.stats.weight_upload_bytes == up + tree
+            assert _on_device(eng)
+            for got, want in zip(jax.tree_util.tree_leaves(eng._params),
+                                 jax.tree_util.tree_leaves(store.fetch()[0])):
+                np.testing.assert_array_equal(np.asarray(got), want)
+        eng.drain()
+        rollouts, metrics = eng.collect()
+        return [r.completion_ids for r in rollouts], metrics
+
+    kept, m_kept = run(swap=False)
+    swapped, m_swapped = run(swap=True)
+    assert m_kept["versions"] == [1] and m_swapped["versions"] == [1, 2]
+    assert len(swapped) == len(kept) == 3
+    # the steps after the swap decode with the second version's weights
+    assert swapped != kept
 
 
 def test_scope_records_nested_spans_on_a_tracer():
