@@ -1,7 +1,9 @@
-"""Operations and bytes from a configuration's shapes.
+"""The benchmark's adapter for a Qwen2-type decoder (``chipbench/adapters``):
+the program's ``ModelConfig`` and parameter layout for it, and its
+operations and bytes.
 
-The arithmetic of ``repro/core/model_spec.py`` (parameter counts,
-``decode_flops_per_token``, ``train_flops_per_token``,
+The counts are the arithmetic of ``repro/core/model_spec.py`` (parameter
+counts, ``decode_flops_per_token``, ``train_flops_per_token``,
 ``kv_bytes_per_token``), copied here so that no later change to the program
 moves the yardstick, with attention's term added: a token that attends to
 ``ctx`` keys costs ``4 * n_heads * head_dim * ctx`` operations a layer
@@ -15,6 +17,50 @@ decoder: ``hidden_size``, ``intermediate_size``, ``num_hidden_layers``,
 from __future__ import annotations
 
 from typing import Dict
+
+import jax.numpy as jnp
+
+
+def model_config(config: Dict):
+    """The program's ``ModelConfig`` for a Qwen2-type ``config.json``."""
+    from repro.models.api import ModelConfig
+    d, h = int(config["hidden_size"]), int(config["num_attention_heads"])
+    return ModelConfig(
+        name=config["name"], family="dense",
+        n_layers=int(config["num_hidden_layers"]), d_model=d, n_heads=h,
+        n_kv_heads=int(config["num_key_value_heads"]),
+        d_ff=int(config["intermediate_size"]),
+        vocab=int(config["vocab_size"]),
+        head_dim=int(config.get("head_dim") or d // h),
+        qkv_bias=bool(config.get("attention_bias", True)),
+        tie_embeddings=bool(config["tie_word_embeddings"]),
+        rope_theta=float(config["rope_theta"]),
+        norm_eps=float(config["rms_norm_eps"]),
+        dtype=config["torch_dtype"])
+
+
+def to_program_params(w: Dict, mcfg) -> Dict:
+    """The reference's weights in the program's layout: stacked layers and
+    the vocabulary padded to the program's multiple (padded rows are zero;
+    the engine drops their logits before it samples)."""
+    pad = mcfg.padded_vocab - mcfg.vocab
+    lw = w["layers"]
+    layers = {
+        "attn_norm": lw["input_norm"],
+        "attn": {"wq": lw["q_proj"], "wk": lw["k_proj"], "wv": lw["v_proj"],
+                 "wo": lw["o_proj"]},
+        "ffn_norm": lw["post_attention_norm"],
+        "ffn": {"w_gate": lw["gate_proj"], "w_up": lw["up_proj"],
+                "w_down": lw["down_proj"]},
+    }
+    if mcfg.qkv_bias:
+        layers["attn"].update(bq=lw["q_bias"], bk=lw["k_bias"], bv=lw["v_bias"])
+    params = {"embed": jnp.pad(w["embed"], ((0, pad), (0, 0))),
+              "layers": layers, "final_norm": w["final_norm"]}
+    if not mcfg.tie_embeddings:
+        params["lm_head"] = jnp.pad(w["lm_head"], ((0, 0), (0, pad)))
+    return params
+
 
 DTYPE_BYTES = {"bfloat16": 2, "float16": 2, "float32": 4}
 
@@ -63,10 +109,10 @@ class Shapes:
         per_layer = self.attn_matmul_params + 3 * self.d * self.ff
         return self.layers * per_layer + self.vocab * self.d
 
-    @property
-    def weight_bytes_per_call(self) -> int:
-        """Least weight bytes one forward call reads: every parameter but the
-        embedding rows it does not look up (taken as none)."""
+    def weight_bytes_per_call(self, rows: int) -> int:
+        """Least weight bytes one forward call reads, whatever its ``rows``:
+        every parameter but the embedding rows it does not look up (taken
+        as none)."""
         table = 0 if self.tied else self.embed_params
         return (self.params - table) * self.dtype_bytes
 

@@ -22,7 +22,6 @@ sample of the served requests (``harness/check.py``).
 from __future__ import annotations
 
 import gc
-import importlib
 import time
 from typing import Dict, List
 
@@ -30,14 +29,19 @@ import jax
 import numpy as np
 
 from chipbench.harness import check, common, program
-from chipbench.harness.shapes import Shapes
 from chipbench.harness.traffic import RolloutPlan
 
 
 class Progress:
-    """Tokens, token gaps and the work done, read from the requests."""
+    """Tokens, token gaps and the work done, read from the requests.
 
-    def __init__(self, shapes: Shapes):
+    ``shapes`` is the architecture's counts (``chipbench/adapters``).  The
+    HBM bytes are kept in two sums: the KV each call reads and writes, and
+    the weights, read once per jitted call: each decode step with the slots
+    that got a decode token as its rows, each prefill chunk with its
+    tokens."""
+
+    def __init__(self, shapes):
         self.shapes = shapes
         self.n: Dict[int, int] = {}          # tokens seen per request
         self.t: Dict[int, float] = {}        # time of its latest token
@@ -45,7 +49,8 @@ class Progress:
         self.tokens = 0
         self.gaps: List[float] = []
         self.flops = 0.0
-        self.hbm_bytes = 0.0
+        self.kv_bytes = 0.0
+        self.weight_bytes = 0
         self.prefill_calls = 0
         self.done_seen = 0
         self.served: Dict[int, object] = {}  # requests that got a token
@@ -62,6 +67,7 @@ class Progress:
         shp, kvb = self.shapes, self.shapes.kv_bytes_per_token
         done = program.finished(engine, self.done_seen)
         self.done_seen += len(done)
+        decode_rows = 0
         for r in list(program.active(engine)) + done:
             i, n = r.idx, len(r.tokens)
             a, b = self.pf.get(i, 0), r.prefill_done
@@ -70,7 +76,8 @@ class Progress:
             if b > a:                        # one prefill chunk this step
                 self.prefill_calls += 1
                 self.flops += shp.span_flops(a, b - a)
-                self.hbm_bytes += b * kvb    # reads a tokens, writes b - a
+                self.kv_bytes += b * kvb     # reads a tokens, writes b - a
+                self.weight_bytes += shp.weight_bytes_per_call(b - a)
             self.pf[i] = b
             prev = self.n.get(i, 0)
             if n < prev:
@@ -79,13 +86,16 @@ class Progress:
                 if prev >= 1:                # decode: one token at prev
                     keys = len(r.prompt) + prev
                     self.flops += shp.token_flops(keys)
-                    self.hbm_bytes += keys * kvb
+                    self.kv_bytes += keys * kvb
+                    decode_rows += 1
                 if i in self.t:
                     self.gaps.append(t - self.t[i])
                 self.t[i] = t
                 self.tokens += n - prev
                 self.served[i] = r
             self.n[i] = n
+        if decode_rows:                      # the step's one decode call
+            self.weight_bytes += shp.weight_bytes_per_call(decode_rows)
 
 
 def _submit(engine, group: List) -> None:
@@ -100,16 +110,16 @@ def run(run: common.Run) -> Dict:
     from repro.serve import PagedEngine, ServeConfig
 
     config, traffic = run.config, run.traffic
-    reference = importlib.import_module(
-        f"chipbench.reference.{config['architecture']}")
-    mcfg = program.model_config(config)
-    shapes = Shapes(config)
+    adapter = program.adapter(config)
+    reference = program.reference(config)
+    mcfg = adapter.model_config(config)
+    shapes = adapter.Shapes(config)
     plan = RolloutPlan(traffic, shapes.vocab, run.seed)
     key = common.seed_key(run.seed)
 
     phases = {}
     mark = lambda name: phases.__setitem__(name, time.perf_counter() - run.t0)
-    params = jax.jit(lambda k: program.to_program_params(
+    params = jax.jit(lambda k: adapter.to_program_params(
         reference.init_weights(k, config), mcfg))(key)
     program.check_layout(params, mcfg)
     store = WeightStore()
@@ -176,8 +186,6 @@ def run(run: common.Run) -> Dict:
     d_steps = st.decode_steps - stats0["decode_steps"]
     kept = ((st.decode_slot_steps - stats0["decode_slot_steps"])
             - (st.preempted_slot_steps - stats0["preempted_slot_steps"]))
-    progress.hbm_bytes += ((d_steps + progress.prefill_calls)
-                           * shapes.weight_bytes_per_call)
     counts = {
         "steps": steps, "decode_steps": d_steps,
         "prefill_calls": progress.prefill_calls,
@@ -225,7 +233,10 @@ def run(run: common.Run) -> Dict:
         },
         "layer": {
             "stage": "rollout", "window_s": window_s, "steps": steps,
-            "flops": progress.flops, "hbm_bytes": progress.hbm_bytes,
+            "flops": progress.flops,
+            "hbm_bytes": progress.kv_bytes + progress.weight_bytes,
+            "hbm_kv_bytes": progress.kv_bytes,
+            "hbm_weight_bytes": progress.weight_bytes,
             "slot_occupancy": occupancy, "trace": common.reduce_trace(box),
             "peaks": run.peaks,
         },

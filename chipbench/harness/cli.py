@@ -2,7 +2,11 @@
 
     python3 chipbench/run.py --workload NAME --seed N --seconds S --trace 0|1
 
-Finds the cell by name, its configuration file and its traffic file
+Finds the cell by name, its configuration file, the adapter and the
+reference of the configuration's ``architecture``
+(``chipbench/adapters/<architecture>.py``,
+``chipbench/reference/<architecture>.py``; an architecture without both
+is refused before any device work), its traffic file
 (``chipbench/traffic/<traffic>.json``), and the driver that the traffic's
 ``kind`` names (``chipbench/drivers/<kind>.py``).  With ``--trace 0`` the
 result carries the cell's end-to-end metrics; with ``--trace 1`` the window
@@ -44,6 +48,12 @@ def load_cell(name: str) -> Dict:
     config = next(c for c in bench["configs"] if c["name"] == cell["config"])
     with open(os.path.join(ROOT, config["file"])) as f:
         config_data = json.load(f)
+    from chipbench.harness import program
+    try:
+        program.adapter(config_data)
+        program.reference(config_data)
+    except program.UnknownArchitecture as e:
+        raise Refused(str(e)) from None
     with open(os.path.join(BENCH, "traffic",
                            cell["traffic"] + ".json")) as f:
         traffic = json.load(f)

@@ -1,7 +1,8 @@
 """Least bytes the window's engine calls must move, over the window's
 seconds, as a share of the chip's HBM bandwidth: the weights once per
 jitted call, the live KV each token reads and the KV it writes
-(``harness/shapes.py``; counted by ``drivers/rollout.Progress``)."""
+(the architecture's ``Shapes`` in ``adapters/<architecture>.py``;
+counted by ``drivers/rollout.Progress``)."""
 from chipbench.harness.readers import share_of_peak
 
 

@@ -1,14 +1,16 @@
-"""Counts from shapes, the peaks table, the traffic generator and the
-contract of BENCHMARK.json, on the CPU."""
+"""Counts from shapes, the peaks table, the traffic generator, the lookup of
+an architecture's modules and the contract of BENCHMARK.json, on the CPU."""
 import json
 import os
 import re
+import shutil
+import time
 
 import numpy as np
 import pytest
 
-from chipbench.harness import common
-from chipbench.harness.shapes import Shapes
+from chipbench.adapters.qwen2 import Shapes
+from chipbench.harness import cli, common, program
 from chipbench.harness.traffic import RolloutPlan, pages_for
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -30,6 +32,22 @@ def test_qd1_5b_counts_by_hand():
     # K and V, 28 layers, 2 KV heads of 128, two bytes
     assert s.kv_bytes_per_token == 2 * 28 * 2 * 128 * 2 == 28_672
     assert s.matmul_params == 28 * (layer - 2048 - 3072) + 151_936 * 1536
+
+
+def test_weight_bytes_per_call_reads_every_weight_but_the_lookup_table():
+    s = Shapes(_json("chipbench", "configs", "qd1_5b.json"))
+    want = (1_777_088_000 - 151_936 * 1536) * 2
+    assert [s.weight_bytes_per_call(r) for r in (1, 13, 2048)] == [want] * 3
+
+
+def test_adapter_lookup_gives_the_programs_qwen2_config():
+    from repro.models.api import ModelConfig
+    config = _json("chipbench", "configs", "qd1_5b.json")
+    assert program.adapter(config).model_config(config) == ModelConfig(
+        name="qd1_5b", family="dense", n_layers=28, d_model=1536,
+        n_heads=12, n_kv_heads=2, head_dim=128, d_ff=8960, vocab=151_936,
+        qkv_bias=True, tie_embeddings=False, rope_theta=10000.0,
+        norm_eps=1e-6, dtype="bfloat16")
 
 
 def test_span_flops_is_sum_of_token_flops():
@@ -80,6 +98,10 @@ def test_benchmark_json_contract():
         assert set(c) == {"name", "source", "file", "reduced", "why"}
         assert c["file"].startswith("chipbench/")
         assert _json(c["file"])["name"] == c["name"]
+        arch = _json(c["file"])["architecture"]
+        for package in ("adapters", "reference"):
+            assert os.path.exists(os.path.join(
+                ROOT, "chipbench", package, arch + ".py"))
     cells = [w["name"] for w in b["workloads"]]
     for w in b["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
@@ -117,3 +139,31 @@ def test_command_refuses_a_cpu_and_prints_no_result():
     assert p.returncode != 0
     assert p.stdout.strip() == ""
     assert "no accelerator" in p.stderr
+
+
+def test_unknown_architecture_is_refused_before_device_work(
+        tmp_path, monkeypatch, capsys):
+    """A copy of the benchmark whose configuration names an architecture
+    with neither an adapter nor a reference: the command refuses it while
+    it loads the cell, before it looks for a chip."""
+    config = dict(_json("chipbench", "configs", "qd1_5b.json"),
+                  architecture="nosuch")
+    bench = tmp_path / "chipbench"
+    (bench / "configs").mkdir(parents=True)
+    (bench / "configs" / "qd1_5b.json").write_text(json.dumps(config))
+    shutil.copytree(os.path.join(ROOT, "chipbench", "traffic"),
+                    bench / "traffic")
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
+    monkeypatch.setattr(cli, "ROOT", str(tmp_path))
+    monkeypatch.setattr(cli, "BENCH", str(bench))
+
+    def no_device_work(chips):
+        raise AssertionError("looked for a chip before the refusal")
+    monkeypatch.setattr(cli, "accelerator", no_device_work)
+    rc = cli.main(["--workload", "rollout.longcot.qd1_5b",
+                   "--seed", str(2 ** 40 + 7), "--seconds", "1"],
+                  time.perf_counter())
+    out, err = capsys.readouterr()
+    assert rc == 2 and out == ""
+    assert "chipbench/adapters/nosuch.py" in err
+    assert "chipbench/reference/nosuch.py" in err

@@ -86,7 +86,7 @@ def main(argv) -> int:
         return 1
     with open(os.path.join(ROOT, "chipbench", "configs", "qd1_5b.json")) as f:
         config = dict(json.load(f), num_hidden_layers=2)
-    mcfg = program.model_config(config)
+    mcfg = program.adapter(config).model_config(config)
     store = WeightStore()
     store.publish(get_model(mcfg).init(jax.random.PRNGKey(0), mcfg))
     engine = PagedEngine(
