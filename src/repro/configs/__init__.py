@@ -30,10 +30,12 @@ _ARCH_MODULES = {
     "qwen-distill-1.5b": "qwen_distill_1_5b",
     "qwen-distill-7b": "qwen_distill_7b",
     "qwen-distill-14b": "qwen_distill_14b",
+    # --- the paged engine's other benchmark models ---
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 ASSIGNED_ARCHS: List[str] = list(_ARCH_MODULES)[:10]
-PAPER_ARCHS: List[str] = list(_ARCH_MODULES)[10:]
+PAPER_ARCHS: List[str] = list(_ARCH_MODULES)[10:13]
 
 
 def list_archs() -> List[str]:

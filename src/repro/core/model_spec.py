@@ -33,6 +33,8 @@ class ModelSpec:
     encoder_seq: int = 0                # stub-frontend sequence length (frames/patches)
     tie_embeddings: bool = False
     mlp_mats: int = 3                   # 3 = SwiGLU, 2 = GELU MLP
+    kv_latent_dim: int = 0              # latent attention: values cached a
+                                        # layer a token (c_kv + shared k_pe)
 
     # ---------------------------------------------------------------- derived
     @property
@@ -96,6 +98,8 @@ class ModelSpec:
         """KV-cache bytes appended per generated token."""
         if self.family == "ssm":
             return 0.0
+        if self.kv_latent_dim:
+            return self.n_layers * self.kv_latent_dim * dtype_bytes
         return 2 * self.n_layers * self.kv_dim * dtype_bytes
 
     def state_bytes(self, dtype_bytes: int = 2) -> float:

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.model_spec import ModelSpec
+from repro.models.blocks import YaRN
 
 
 def _round_up(x: int, m: int) -> int:
@@ -59,6 +60,20 @@ class ModelConfig:
                                       # expert down-projection einsum so the
                                       # TP partial-sum all-reduce lands on
                                       # [tokens, d] instead of [g, E, C, d]
+    d_ff_shared: int = 0              # shared-expert SwiGLU width (0: none)
+    first_k_dense: int = 0            # leading layers with a dense FFN ...
+    d_ff_dense: int = 0               # ... of this width
+    norm_topk_prob: bool = True       # renormalize the top-k gates to sum 1
+    routed_scaling: float = 1.0       # routed experts' output multiplier
+    expert_offset: int = 0            # global id of the first expert held
+    experts_held: int = 0             # experts the params hold (0: all);
+                                      # the router still spans n_experts
+    # --- latent attention (MLA; kv_lora_rank > 0 selects it)
+    kv_lora_rank: int = 0             # width of the cached latent c_kv
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0         # roped part of q/k; one shared k_pe
+    v_head_dim: int = 0
+    rope_scaling: Optional[YaRN] = None
     # --- SSM / hybrid
     ssm_state: int = 0
     attn_window: Optional[int] = None # SWA window; None = full attention
@@ -92,6 +107,14 @@ class ModelConfig:
         return self.head_dim if self.head_dim else self.d_model // self.n_heads
 
     @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.n_experts
+
+    @property
     def padded_vocab(self) -> int:
         return _round_up(self.vocab, self.vocab_pad_to)
 
@@ -117,6 +140,8 @@ class ModelConfig:
             encoder_seq=self.encoder_seq,
             tie_embeddings=self.tie_embeddings,
             mlp_mats=2 if self.mlp_kind == "gelu" else 3,
+            kv_latent_dim=(self.kv_lora_rank + self.qk_rope_head_dim
+                           if self.mla else 0),
         )
 
     def replace(self, **kw) -> "ModelConfig":

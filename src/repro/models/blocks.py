@@ -9,6 +9,7 @@ are validated against, and the path XLA compiles inside the dry-run.
 from __future__ import annotations
 
 import math
+from dataclasses import dataclass
 from functools import partial
 from typing import Optional, Tuple
 
@@ -67,6 +68,58 @@ def apply_rope(x: Array, positions: Array, theta: float) -> Array:
     return out.astype(x.dtype)
 
 
+# ---------------------------------------------------------------- YaRN RoPE
+@dataclass(frozen=True)
+class YaRN:
+    """YaRN context extension of RoPE (arXiv:2309.00071), the
+    ``rope_scaling`` of DeepSeek-V2's config.json."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _yarn_bound(rotations: float, dim: int, theta: float, max_pos: int) -> float:
+    """Rotary pair index whose wavelength fits ``rotations`` turns into the
+    original context."""
+    return (dim * math.log(max_pos / (rotations * 2 * math.pi))
+            / (2 * math.log(theta)))
+
+
+def yarn_inv_freq(dim: int, theta: float, y: YaRN) -> Array:
+    """Inverse frequencies of ``dim`` rotary dims: pairs below the low
+    correction bound keep theta's, pairs above the high bound are divided by
+    ``factor``, with a linear ramp between."""
+    low = max(math.floor(_yarn_bound(y.beta_fast, dim, theta,
+                                     y.original_max_position)), 0)
+    high = min(math.ceil(_yarn_bound(y.beta_slow, dim, theta,
+                                     y.original_max_position)), dim - 1)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    extra = rope_freqs(dim, theta)
+    return extra / y.factor * ramp + extra * (1.0 - ramp)
+
+
+def apply_rope_interleaved(x: Array, positions: Array, inv_freq: Array,
+                           mscale: float = 1.0) -> Array:
+    """RoPE over rotary pairs ``(2i, 2i+1)`` (DeepSeek-V2's checkpoint
+    layout), returned rotate-half style: evens' rotation, then odds'.
+    x: [..., S, H, D]; positions broadcastable to [..., S]."""
+    angles = positions[..., None].astype(jnp.float32) * inv_freq
+    cos = jnp.cos(angles)[..., None, :] * mscale
+    sin = jnp.sin(angles)[..., None, :] * mscale
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., 0::2], xf[..., 1::2]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.astype(x.dtype)
+
+
 # ------------------------------------------------------------- attention core
 NEG_INF = -1e30
 
@@ -111,6 +164,7 @@ def attention(
     """
     B, Sq, H, D = q.shape
     _, Sk, Hkv, _ = k.shape
+    Dv = v.shape[-1]
     assert H % Hkv == 0, (H, Hkv)
     G = H // Hkv
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
@@ -135,7 +189,7 @@ def attention(
         l = jnp.sum(p, axis=-1, keepdims=True)
         o = jnp.einsum("bhgk,bkhd->bhgd", p, v.astype(jnp.float32))
         o = o / jnp.maximum(l, 1e-30)
-        return o.reshape(B, 1, H, D).astype(q.dtype)
+        return o.reshape(B, 1, H, Dv).astype(q.dtype)
 
     q_chunk = min(q_chunk, Sq)
     kv_chunk = min(kv_chunk, Sk)
@@ -158,7 +212,7 @@ def attention(
     qg = q.reshape(B, nq, q_chunk, Hkv, G, D)
     qpos = q_positions.reshape(B, nq, q_chunk)
     kg = k.reshape(B, nk, kv_chunk, Hkv, D)
-    vg = v.reshape(B, nk, kv_chunk, Hkv, D)
+    vg = v.reshape(B, nk, kv_chunk, Hkv, Dv)
     kpos = k_positions.reshape(B, nk, kv_chunk)
 
     def one_q_block(qb, qpb):
@@ -180,7 +234,7 @@ def attention(
             acc_new = acc * corr[..., None] + pv
             return (acc_new, m_new, l_new), None
 
-        acc0 = jnp.zeros((B, q_chunk, Hkv, G, D), jnp.float32)
+        acc0 = jnp.zeros((B, q_chunk, Hkv, G, Dv), jnp.float32)
         m0 = jnp.full((B, q_chunk, Hkv, G), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, q_chunk, Hkv, G), jnp.float32)
         (acc, m, l), _ = lax.scan(
@@ -193,7 +247,7 @@ def attention(
         out = one_q_block(qg[:, 0], qpos[:, 0])[:, None]
     else:
         out = jax.vmap(one_q_block, in_axes=(1, 1), out_axes=1)(qg, qpos)
-    out = out.reshape(B, Sqp, H, D)[:, :Sq]
+    out = out.reshape(B, Sqp, H, Dv)[:, :Sq]
     return out.astype(q.dtype)
 
 
@@ -213,6 +267,98 @@ def qkv_project(x: Array, p: dict, n_heads: int, n_kv_heads: int,
     k = k.reshape(B, S, n_kv_heads, head_dim)
     v = v.reshape(B, S, n_kv_heads, head_dim)
     return q, k, v
+
+
+# ------------------------------------------------------ latent attention (MLA)
+# DeepSeek-V2 (arXiv:2405.04434), without query compression: q from x; a
+# latent c_kv (normed, kv_lora_rank wide) and one roped k_pe shared by all
+# heads from x; per head k_nope = c_kv W_uk and v = c_kv W_uv.  The cache
+# holds (c_kv, k_pe).  ``cfg`` is the model's ``ModelConfig``.
+def mla_rope(cfg) -> Tuple[Array, float]:
+    """Inverse frequencies of the roped dims and the cos/sin scale."""
+    d, y = cfg.qk_rope_head_dim, cfg.rope_scaling
+    if y is None:
+        return rope_freqs(d, cfg.rope_theta), 1.0
+    return (yarn_inv_freq(d, cfg.rope_theta, y),
+            yarn_mscale(y.factor, y.mscale)
+            / yarn_mscale(y.factor, y.mscale_all_dim))
+
+
+def mla_softmax_scale(cfg) -> float:
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    y = cfg.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
+def init_mla_params(rng, cfg, dtype):
+    H, r = cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    ks = jax.random.split(rng, 5)
+    return {
+        "wq": dense_init(ks[0], cfg.d_model, H * (dn + dr), dtype),
+        "wkv_a": dense_init(ks[1], cfg.d_model, r + dr, dtype),
+        "kv_norm": jnp.ones((r,), dtype),
+        "w_uk": dense_init(ks[2], r, H * dn, dtype).reshape(r, H, dn),
+        "w_uv": dense_init(ks[3], r, H * dv, dtype).reshape(r, H, dv),
+        "wo": dense_init(ks[4], H * dv, cfg.d_model, dtype),
+    }
+
+
+def mla_project(x: Array, p: dict, positions: Array, cfg
+                ) -> Tuple[Array, Array, Array, Array]:
+    """x [B,S,d] -> q_nope [B,S,H,dn], q_pe [B,S,H,dr] (roped), the normed
+    latent c_kv [B,S,r] and the roped shared k_pe [B,S,dr]."""
+    B, S, _ = x.shape
+    dn, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    q = jnp.einsum("bsd,dh->bsh", x, p["wq"]).reshape(B, S, cfg.n_heads, -1)
+    kv = jnp.einsum("bsd,dh->bsh", x, p["wkv_a"])
+    c_kv = rms_norm(kv[..., :r], p["kv_norm"], cfg.norm_eps)
+    inv_freq, mscale = mla_rope(cfg)
+    q_pe = apply_rope_interleaved(q[..., dn:], positions, inv_freq, mscale)
+    k_pe = apply_rope_interleaved(kv[..., None, r:], positions, inv_freq,
+                                  mscale)[..., 0, :]
+    return q[..., :dn], q_pe, c_kv, k_pe
+
+
+def mla_expand(c_kv: Array, k_pe: Array, p: dict) -> Tuple[Array, Array]:
+    """Expanded form: latent [B,T,r] and k_pe [B,T,dr] -> per-head keys
+    [B,T,H,dn+dr] and values [B,T,H,dv]."""
+    k_nope = jnp.einsum("btr,rhn->bthn", c_kv, p["w_uk"])
+    v = jnp.einsum("btr,rhv->bthv", c_kv, p["w_uv"])
+    k_pe = jnp.broadcast_to(k_pe[:, :, None, :],
+                            k_nope.shape[:3] + k_pe.shape[-1:])
+    return jnp.concatenate([k_nope, k_pe.astype(k_nope.dtype)], -1), v
+
+
+def mla_absorbed_attention(q_nope: Array, q_pe: Array, c_kv: Array,
+                           k_pe: Array, q_positions: Array,
+                           k_positions: Array, p: dict, cfg) -> Array:
+    """Absorbed form, one query a row: q_nope [B,H,dn], q_pe [B,H,dr] at
+    q_positions [B] against the cached latent c_kv [B,T,r] and k_pe
+    [B,T,dr] at k_positions [B,T] (causal, masked as ``attention`` masks).
+    Scores (q_nope W_uk^T) . c_kv + q_pe . k_pe, output (p . c_kv) W_uv
+    -> [B,H,dv].  No key or value is expanded."""
+    with jax.named_scope("mla.absorb"):
+        q_abs = jnp.einsum("bhn,rhn->bhr", q_nope, p["w_uk"],
+                           preferred_element_type=jnp.float32)
+    with jax.named_scope("mla.attend"):
+        s = (jnp.einsum("bhr,btr->bht", q_abs.astype(c_kv.dtype), c_kv,
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("bhp,btp->bht", q_pe.astype(k_pe.dtype), k_pe,
+                          preferred_element_type=jnp.float32))
+        mask = _mask_value(q_positions[:, None], k_positions, True,
+                           cfg.attn_window, None)                 # [B,1,T]
+        s = s * mla_softmax_scale(cfg) + mask
+        m = jnp.max(s, axis=-1, keepdims=True)
+        e = jnp.exp(s - m)
+        l = jnp.sum(e, axis=-1, keepdims=True)
+        ctx = jnp.einsum("bht,btr->bhr", e.astype(c_kv.dtype), c_kv,
+                         preferred_element_type=jnp.float32)
+        ctx = ctx / jnp.maximum(l, 1e-30)
+    with jax.named_scope("mla.absorb"):
+        return jnp.einsum("bhr,rhv->bhv", ctx.astype(q_nope.dtype), p["w_uv"])
 
 
 def out_project(o: Array, p: dict) -> Array:

@@ -129,6 +129,9 @@ class EngineStats:
                                        # passed to the jitted decode/prefill
     weight_upload_bytes: int = 0       # host bytes of the weight versions
                                        # moved to the device (one per fetch)
+    expert_rows: Tuple[int, ...] = ()  # (row, choice) pairs routed to each
+                                       # held expert (expert family)
+    routed_pairs: int = 0              # all (row, choice) pairs routed
     wall_time_s: float = 0.0
     page_occ_sum: float = 0.0
     pool_util_sum: float = 0.0
@@ -193,7 +196,8 @@ class EngineStats:
                      "tokens_generated", "preempted_slot_steps",
                      "weight_swaps", "admissions", "preemptions",
                      "completed", "forks", "cow_copies", "bt_uploads",
-                     "host_arg_bytes", "weight_upload_bytes"):
+                     "host_arg_bytes", "weight_upload_bytes",
+                     "routed_pairs"):
             reg.counter(f"engine/{name}").inc(getattr(self, name))
         reg.gauge("engine/max_slots").set(self.max_slots)
         reg.gauge("engine/wall_time_s").set(self.wall_time_s)
@@ -274,10 +278,11 @@ class PagedEngine:
                  gen: Optional[GenConfig] = None,
                  serve: Optional[ServeConfig] = None, rng_seed: int = 0,
                  tracer: Optional[Tracer] = None):
-        if cfg.family not in ("dense", "vlm"):
+        if cfg.family not in ("dense", "vlm", "moe"):
             raise ValueError(
-                f"paged serving covers the dense-transformer family; "
-                f"{cfg.family!r} models use the static RolloutEngine")
+                f"paged serving covers the dense-transformer and expert "
+                f"families; {cfg.family!r} models use the static "
+                f"RolloutEngine")
         self.cfg = cfg
         self.store = store
         # wall-clock tracer (repro.obs); None records no spans — the
@@ -299,6 +304,8 @@ class PagedEngine:
         self._active: Dict[int, _Request] = {}       # slot → request
         self._done: List[_Request] = []
         self._bt_dev: Optional[jax.Array] = None     # cached device table
+        # routing counts of the calls since the last fetch (expert family)
+        self._routed: List[Dict[str, jax.Array]] = []
         # named programs (``jit_paged_decode_step`` in a profile); the
         # config is a static argument
         self._decode = jax.jit(paged_decode_step, static_argnums=1)
@@ -347,6 +354,21 @@ class PagedEngine:
         return (req.temperature == self.gen.temperature
                 and req.top_p == getattr(self.gen, "top_p", 1.0)
                 and req.greedy == self.gen.greedy)
+
+    def _fetch(self, sampled):
+        """Device to host: the sampled tokens and logps, and with them the
+        routing counts of the calls since the last fetch (the expert family
+        returns them from its programs; the dense family fetches nothing
+        more), added to ``stats``."""
+        sampled, routed = jax.device_get((sampled, self._routed))
+        self._routed = []
+        for counts in routed:
+            rows = np.asarray(counts["expert_rows"], np.int64)
+            if self.stats.expert_rows:
+                rows = rows + np.asarray(self.stats.expert_rows)
+            self.stats.expert_rows = tuple(int(r) for r in rows)
+            self.stats.routed_pairs += int(counts["routed_pairs"])
+        return sampled
 
     def _load_weights(self) -> None:
         """Fetch the store's latest version and keep it on the device, so
@@ -765,10 +787,11 @@ class PagedEngine:
                     self.stats.bt_uploads += 1
                 self.stats.host_arg_bytes += _host_bytes(token, pos, active)
             with scope(tr, "dispatch"):
-                logits, nk, nv = self._decode(
+                logits, nk, nv, *routed = self._decode(
                     self._params, self.cfg, self.kv.k_pages, self.kv.v_pages,
                     self._bt_dev, token, pos, active)
             self.kv.k_pages, self.kv.v_pages = nk, nv
+            self._routed.extend(routed)
             batched = all(self._default_params(self._active[s])
                           for s in slots)
             with scope(tr, "sample"):
@@ -783,7 +806,7 @@ class PagedEngine:
                                                 self._active[s])
                                for s in slots]
             with scope(tr, "wait"):
-                sampled = jax.device_get(sampled)
+                sampled = self._fetch(sampled)
             with scope(tr, "bookkeep"):
                 if batched:
                     toks, logps = sampled
@@ -849,12 +872,15 @@ class PagedEngine:
             assert ok, "admission reserved these"
             row = self.kv.block_tables[req.slot]
             p0 = np.int32(req.prefill_done)
-            self.stats.host_arg_bytes += _host_bytes(row, toks, p0)
+            # the expert layer is told which rows hold tokens
+            rows = (np.int32(n),) if self.cfg.family == "moe" else ()
+            self.stats.host_arg_bytes += _host_bytes(row, toks, p0, *rows)
             with scope(tr, "dispatch"):
-                logits, nk, nv = self._prefill(
+                logits, nk, nv, *routed = self._prefill(
                     self._params, self.cfg, self.kv.k_pages, self.kv.v_pages,
-                    row, toks, p0)
+                    row, toks, p0, *rows)
             self.kv.k_pages, self.kv.v_pages = nk, nv
+            self._routed.extend(routed)
             req.prefill_done += n
             self.stats.prefill_tokens += n
             if req.prefill_done >= req.plen:
@@ -865,7 +891,7 @@ class PagedEngine:
                     sampled = [self._sample_req(last, self._split(), r)
                                for r in [req] + req.forks]
                 with scope(tr, "wait"):
-                    sampled = jax.device_get(sampled)
+                    sampled = self._fetch(sampled)
                 first, logp = sampled[0]
                 req.tokens.append(int(first))
                 req.logps.append(float(logp))

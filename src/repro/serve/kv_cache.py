@@ -1,7 +1,8 @@
 """Paged KV cache: fixed-size blocks, block tables, refcounted COW pool.
 
-Device side, the cache is two pools ``[L, P, page, Hkv, D]`` (keys and
-values for every layer) plus an int32 block table ``[max_slots, maxp]``;
+Device side, the cache is two pools ``[L, P, page, ...]`` (keys and values
+``[Hkv, D]`` a token a layer; for latent attention the latent c_kv and the
+shared k_pe, ``pool_tails``) plus an int32 block table ``[max_slots, maxp]``;
 host side, this class is the allocator: a LIFO free list of page ids, a
 free list of sequence slots, per-slot length bookkeeping, and a per-page
 reference count.
@@ -59,6 +60,15 @@ from repro.kernels import tuning
 from repro.models.api import ModelConfig
 
 
+def pool_tails(cfg: ModelConfig):
+    """Trailing shapes of the two pools: ``(n_kv_heads, hd)`` for keys and
+    values; for latent attention ``(1, kv_lora_rank)`` for the normed latent
+    c_kv and ``(1, qk_rope_head_dim)`` for the roped shared k_pe."""
+    if cfg.mla:
+        return (1, cfg.kv_lora_rank), (1, cfg.qk_rope_head_dim)
+    return (cfg.n_kv_heads, cfg.hd), (cfg.n_kv_heads, cfg.hd)
+
+
 @partial(jax.jit, donate_argnums=(0,))
 def _copy_page(pages: jax.Array, src: jax.Array, dst: jax.Array) -> jax.Array:
     """COW page copy ``pages[:, dst] = pages[:, src]``.  The pool is
@@ -87,10 +97,10 @@ class PagedKVCache:
         if self.num_pages < 2:
             raise ValueError("pool needs the null page plus ≥1 usable page")
 
-        shape = (cfg.n_layers, self.num_pages, self.page, cfg.n_kv_heads,
-                 cfg.hd)
-        self.k_pages = jnp.zeros(shape, cfg.jdtype)
-        self.v_pages = jnp.zeros(shape, cfg.jdtype)
+        lead = (cfg.n_layers, self.num_pages, self.page)
+        k_tail, v_tail = pool_tails(cfg)
+        self.k_pages = jnp.zeros(lead + k_tail, cfg.jdtype)
+        self.v_pages = jnp.zeros(lead + v_tail, cfg.jdtype)
         self.block_tables = np.zeros((max_slots, self.maxp), np.int32)
         self.seq_lens = np.zeros((max_slots,), np.int32)
 
