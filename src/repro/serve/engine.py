@@ -113,6 +113,8 @@ class EngineStats:
     max_slots: int = 0
     decode_steps: int = 0              # batched decode invocations
     decode_slot_steps: int = 0         # Σ active slots over decode steps
+    decode_pages_read: int = 0         # Σ live (slot, page) pairs the decode
+                                       # attention visited over decode steps
     prefill_tokens: int = 0            # prompt tokens actually computed
     prefill_tokens_shared: int = 0     # prompt tokens served without compute
     radix_hit_tokens: int = 0          # ... of which came from the radix tree
@@ -191,7 +193,8 @@ class EngineStats:
         read the registry snapshot instead of reaching into stat fields,
         so new engine internals never break the feedback loop."""
         reg = MetricsRegistry()
-        for name in ("decode_steps", "decode_slot_steps", "prefill_tokens",
+        for name in ("decode_steps", "decode_slot_steps",
+                     "decode_pages_read", "prefill_tokens",
                      "prefill_tokens_shared", "radix_hit_tokens",
                      "tokens_generated", "preempted_slot_steps",
                      "weight_swaps", "admissions", "preemptions",
@@ -821,6 +824,7 @@ class PagedEngine:
                         r.max_new = len(r.tokens)           # stop this row
                 self.stats.decode_steps += 1
                 self.stats.decode_slot_steps += len(slots)
+                self.stats.decode_pages_read += self._pages_read(pos[slots])
                 occ = self.kv.occupancy()
                 self.stats.page_occ_sum += occ["page_occupancy"]
                 self.stats.pool_util_sum += occ["pool_util"]
@@ -830,6 +834,14 @@ class PagedEngine:
                     tr.counter("engine", "pages", tr.now(),
                                free=self.kv.free_pages,
                                occupancy=occ["page_occupancy"])
+
+    def _pages_read(self, pos: np.ndarray) -> int:
+        """Live pages of decode queries at ``pos``: those holding a key the
+        query sees, as the decode attention lists them (``model``'s
+        ``_live_pages``): sum ceil(written / page) without a window."""
+        written, page, window = pos + 1, self.kv.page, self.cfg.attn_window
+        first = np.maximum(written - window, 0) // page if window else 0
+        return int(np.sum(-(-written // page) - first))
 
     def _fork_siblings(self, leader: _Request,
                        sampled: List[Tuple[int, float]]) -> None:

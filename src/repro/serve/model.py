@@ -3,11 +3,11 @@
 Mirrors ``models/transformer.py``'s prefill/decode math exactly (same
 blocks, same rope, same masked-softmax attention semantics) but reads and
 writes the **paged** cache: per step, new K/V land at logical slot
-``pos`` → physical ``(table[pos // page], pos % page)``, and attention
-runs either through the Pallas ``paged_attention`` kernel
-(``cfg.use_pallas``) or a gather + ``blocks.attention`` reference path
-whose extra pool slots are exactly masked — so a paged greedy decode is
-token-identical to the dense engine's.
+``pos`` → physical ``(table[pos // page], pos % page)``, and decode
+attention runs either through the Pallas ``paged_attention`` kernel
+(``cfg.use_pallas``) or a split-K loop over the live pages (below), each
+masking every pool slot past the context exactly — so a paged greedy
+decode is token-identical to the dense engine's.
 
 Prefill is *chunked* (one sequence, ``chunk`` tokens per call): the chunk
 writes its K/V into the pages first, then attends over the gathered table
@@ -23,9 +23,16 @@ a fourth value, the step's routing counts summed over layers.  Its
 DeepSeek-V2 block (latent attention, ``cfg.mla``) has programs of its own:
 the pools hold the latent c_kv and the shared k_pe, carried through the
 layers (the dense layers first, then a scan over the expert layers).
-Decode attends in the absorbed form over the densified table; prefill
-writes the chunk's latent, then expands keys and values from the table's
-latent and attends as the dense family does.
+Decode attends in the absorbed form; prefill writes the chunk's latent,
+then expands keys and values from the table's latent and attends as the
+dense family does.
+
+Decode attention (GQA with ``use_pallas`` off, and the latent form) reads
+only the live pages: the (slot, page) pairs whose page holds a key some
+decoding slot's query sees are listed once a step, and each layer runs one
+split-K loop over them, ``DECODE_PAGE_BLOCK`` pairs a trip, merging each
+pair's softmax terms into its slot's by log-sum-exp rescaling.  The trip
+count follows the batch's contexts, not ``max_len``.
 """
 from __future__ import annotations
 
@@ -40,15 +47,6 @@ from repro.models.api import ModelConfig
 from repro.models.transformer import _ffn, embed_inputs, unembed
 
 Array = jax.Array
-
-
-def _key_positions(written: Array, B: int, C: int) -> Array:
-    """[B, C] position of each densified table slot, masked (-2^30) at and
-    beyond ``written`` [B] (stale pool data), as ``_gather_attention``
-    masks them."""
-    written = jnp.broadcast_to(jnp.atleast_1d(written), (B,))
-    slot = jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32), (B, C))
-    return jnp.where(slot < written[:, None], slot, -(2 ** 30))
 
 
 def _mlp(x: Array, lp: Dict, cfg: ModelConfig, rows):
@@ -71,11 +69,109 @@ def _with_counts(out: Tuple, counts) -> Tuple:
                                          counts),)
 
 
+DECODE_PAGE_BLOCK = 128       # (slot, page) pairs a trip of decode attention
+PREFILL_KEY_BLOCK = 1024      # keys a step of the latent prefill attention
+
+
+def _live_pages(table: Array, pos: Array, active: Array, page: int,
+                window: Optional[int]) -> Tuple:
+    """The step's live (slot, page) pairs, slot-major: page j of an active
+    slot is live when it holds a key the slot's query at ``pos`` sees
+    (``j * page <= pos`` and, with a window, a key above ``pos - window``).
+    -> (slot, page index, physical page) [N] each and the traced count
+    ``n_live``; N covers every pair in whole trips, and the pairs from
+    ``n_live`` on repeat the last slot's last page, which the loop masks."""
+    S, maxp = table.shape
+    j = jnp.arange(maxp, dtype=jnp.int32)
+    live = (active[:, None] > 0) & (j * page <= pos[:, None])
+    if window is not None:
+        live &= (j + 1) * page > pos[:, None] - window + 1
+    live = live.reshape(-1)
+    N = -(-S * maxp // DECODE_PAGE_BLOCK) * DECODE_PAGE_BLOCK
+    (pair,) = jnp.nonzero(live, size=N, fill_value=S * maxp - 1)
+    slot, idx = pair // maxp, pair % maxp
+    return slot, idx, table[slot, idx], jnp.sum(live, dtype=jnp.int32)
+
+
+def _split_k_attention(pairs: Tuple, pos: Array, page: int,
+                       window: Optional[int], heads: Tuple[int, ...], dv: int,
+                       score, value) -> Array:
+    """Decode attention over the live pairs of ``_live_pages``: a loop of
+    ``DECODE_PAGE_BLOCK`` pairs a trip.  ``score(slot [W], pages [W])``
+    gathers the pages and returns the float32 scores [W, *heads, page] of
+    each pair's slot's query against them (softmax scale applied) and the
+    gathered values; ``value(e, values)`` returns the weighted value sums
+    [W, *heads, dv] of the float32 weights e.  Each pair's max, exp-sum and
+    value sum merge into its slot's running (m, l, acc) by log-sum-exp
+    rescaling; keys past ``pos`` or outside the window are masked, as are
+    the pairs past ``n_live``.  -> float32 [S, *heads, dv]; slots with no
+    pair read 0."""
+    slot, idx, phys, n_live = pairs
+    S, W = pos.shape[0], DECODE_PAGE_BLOCK
+    key = jnp.arange(page, dtype=jnp.int32)
+    seg = dict(num_segments=S, indices_are_sorted=True)
+
+    def trip(t, carry):
+        m, l, acc = carry
+        at = t * W
+        sl, pj, pp = (lax.dynamic_slice_in_dim(a, at, W)
+                      for a in (slot, idx, phys))
+        k_pos = pj[:, None] * page + key                             # [W,page]
+        q_pos = pos[sl][:, None]
+        ok = (at + jnp.arange(W) < n_live)[:, None] & (k_pos <= q_pos)
+        if window is not None:
+            ok &= k_pos > q_pos - window
+        s, vals = score(sl, pp)
+        ok = ok.reshape((W,) + (1,) * len(heads) + (page,))
+        s = jnp.where(ok, s, blocks.NEG_INF)
+        mb = jnp.max(s, axis=-1)                                # [W, *heads]
+        e = jnp.where(ok, jnp.exp(s - mb[..., None]), 0.0)
+        m_new = jnp.maximum(m, jax.ops.segment_max(mb, sl, **seg))
+        w = jnp.exp(mb - m_new[sl])
+        corr = jnp.exp(m - m_new)
+        l = l * corr + jax.ops.segment_sum(jnp.sum(e, axis=-1) * w, sl, **seg)
+        acc = acc * corr[..., None] + jax.ops.segment_sum(
+            value(e, vals) * w[..., None], sl, **seg)
+        return m_new, l, acc
+
+    init = (jnp.full((S,) + heads, blocks.NEG_INF, jnp.float32),
+            jnp.zeros((S,) + heads, jnp.float32),
+            jnp.zeros((S,) + heads + (dv,), jnp.float32))
+    _, l, acc = lax.fori_loop(0, (n_live + W - 1) // W, trip, init)
+    return acc / jnp.maximum(l, 1e-30)[..., None]
+
+
+def _paged_gqa_attention(q: Array, kp: Array, vp: Array, pairs: Tuple,
+                         pos: Array, cfg: ModelConfig) -> Array:
+    """One decode query a slot, q [S, 1, H, D], against the live pages of
+    the layer's pools kp, vp [P, page, Hkv, D]: masked attention as
+    ``_gather_attention`` computes it over the densified table, reading
+    only the live pages.  -> [S, 1, H, D]"""
+    S, _, H, D = q.shape
+    page, Hkv = kp.shape[1], kp.shape[2]
+    qg = q.reshape(S, Hkv, H // Hkv, D).astype(kp.dtype)
+    scale = 1.0 / D ** 0.5
+
+    def score(sl, pp):
+        k = kp[pp]                                             # [W,page,Hkv,D]
+        s = jnp.einsum("whgd,wkhd->whgk", qg[sl], k,
+                       preferred_element_type=jnp.float32)
+        return s * scale, vp[pp]
+
+    def value(e, v):
+        return jnp.einsum("whgk,wkhd->whgd", e.astype(v.dtype), v,
+                          preferred_element_type=jnp.float32)
+
+    o = _split_k_attention(pairs, pos, page, cfg.attn_window,
+                           (Hkv, H // Hkv), D, score, value)
+    return o.reshape(S, 1, H, D).astype(q.dtype)
+
+
 def _gather_attention(q: Array, kp: Array, vp: Array, table: Array,
                       q_positions: Array, written: Array,
                       cfg: ModelConfig) -> Array:
-    """Reference path: densify the pool rows named by ``table`` and run the
-    shared masked attention.  ``written`` [B] = logical slots written so
+    """Prefill's attention: densify the pool rows named by ``table`` and run
+    the shared masked attention.  ``written`` [B] = logical slots written so
     far; slots beyond it hold stale pool data and are masked out."""
     B = q.shape[0]
     page = kp.shape[1]
@@ -103,7 +199,8 @@ def paged_decode_step(params: Dict, cfg: ModelConfig, k_pages: Array,
     ``active`` masks the slots decoding this step.  Inactive slots ride
     along with pos=0 and their table row zeroed *here* — writes land in
     the null page and their logits are garbage the engine discards — so
-    the cached table never needs per-step editing on the host.
+    the cached table never needs per-step editing on the host.  Attention
+    reads the pages of the active slots' contexts only (``_live_pages``).
     """
     if cfg.mla:
         return _mla_decode_step(params, cfg, k_pages, v_pages, block_tables,
@@ -115,6 +212,8 @@ def paged_decode_step(params: Dict, cfg: ModelConfig, k_pages: Array,
     positions = pos[:, None]
     page_of = block_tables[jnp.arange(S), pos // page]             # [S]
     off = pos % page
+    pairs = (None if cfg.use_pallas else
+             _live_pages(block_tables, pos, active, page, cfg.attn_window))
 
     def body(h, xs):
         lp, kp, vp = xs                      # kp: [P, page, Hkv, D]
@@ -132,8 +231,7 @@ def paged_decode_step(params: Dict, cfg: ModelConfig, k_pages: Array,
                                        pos + 1,
                                        window=cfg.attn_window)[:, None]
         else:
-            o = _gather_attention(q, kp, vp, block_tables, positions,
-                                  pos + 1, cfg)
+            o = _paged_gqa_attention(q, kp, vp, pairs, pos, cfg)
         h = h + blocks.out_project(o, lp["attn"])
         x = blocks.rms_norm(h, lp["ffn_norm"], cfg.norm_eps)
         y, counts = _mlp(x, lp, cfg, active)
@@ -261,25 +359,21 @@ def _mla_decode_step(params: Dict, cfg: ModelConfig, c_pages: Array,
                      pe_pages: Array, block_tables: Array, token: Array,
                      pos: Array, active: Array) -> Tuple:
     """``paged_decode_step`` for latent attention: each slot's query
-    attends in the absorbed form to the latent of its densified table."""
-    S = token.shape[0]
+    attends in the absorbed form (``blocks.mla_absorbed_attention``'s
+    arithmetic) to the latent of its live pages."""
     page = c_pages.shape[2]
-    T = block_tables.shape[1] * page
     block_tables = jnp.where(active[:, None] > 0, block_tables, 0)
     h = jnp.take(params["embed"], token[:, None], axis=0)          # [S,1,d]
     positions = pos[:, None]
     page_of, off = _page_slots(positions, block_tables, page)
-    k_pos = _key_positions(pos + 1, S, T)
+    pairs = _live_pages(block_tables, pos, active, page, cfg.attn_window)
 
     def attend(x, p, cp, pp, li):
         q_nope, q_pe, c_kv, k_pe = blocks.mla_project(x, p, positions, cfg)
         cp = cp.at[li, page_of, off].set(c_kv[:, :, None].astype(cp.dtype))
         pp = pp.at[li, page_of, off].set(k_pe[:, :, None].astype(pp.dtype))
-        with jax.named_scope("mla.attend"):      # the densified table
-            cd = cp[li, block_tables].reshape(S, T, -1)
-            ped = pp[li, block_tables].reshape(S, T, -1)
-        o = blocks.mla_absorbed_attention(q_nope[:, 0], q_pe[:, 0], cd, ped,
-                                          pos, k_pos, p, cfg)
+        o = _paged_mla_attention(q_nope[:, 0], q_pe[:, 0], cp, pp, li, pairs,
+                                 pos, p, cfg)
         return o[:, None], (cp, pp)
 
     h, (nc, npe), counts = _mla_layers(params, cfg, h, (c_pages, pe_pages),
@@ -287,7 +381,38 @@ def _mla_decode_step(params: Dict, cfg: ModelConfig, c_pages: Array,
     return unembed(params, cfg, h[:, 0]), nc, npe, counts
 
 
-PREFILL_KEY_BLOCK = 1024      # keys a step of the latent prefill attention
+def _paged_mla_attention(q_nope: Array, q_pe: Array, cp: Array, pp: Array,
+                         li, pairs: Tuple, pos: Array, p: Dict,
+                         cfg: ModelConfig) -> Array:
+    """The absorbed latent attention of one decode query a slot, q_nope
+    [S, H, dn] and q_pe [S, H, dr], against the live pages of layer ``li``
+    of the latent pools cp [L, P, page, 1, r] and pp [L, P, page, 1, dr]: the
+    arithmetic of ``blocks.mla_absorbed_attention`` over the densified
+    table, reading only the live pages.  -> [S, H, dv]"""
+    H, r = q_nope.shape[1], cp.shape[-1]
+    scale = blocks.mla_softmax_scale(cfg)
+    with jax.named_scope("mla.absorb"):
+        q_abs = jnp.einsum("shn,rhn->shr", q_nope, p["w_uk"],
+                           preferred_element_type=jnp.float32)
+    q_abs, q_pe = q_abs.astype(cp.dtype), q_pe.astype(pp.dtype)
+
+    def score(sl, pages):
+        c, pe = cp[li, pages, :, 0], pp[li, pages, :, 0]   # [W,page,r|dr]
+        s = (jnp.einsum("whr,wkr->whk", q_abs[sl], c,
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("whp,wkp->whk", q_pe[sl], pe,
+                          preferred_element_type=jnp.float32))
+        return s * scale, c
+
+    def value(e, c):
+        return jnp.einsum("whk,wkr->whr", e.astype(c.dtype), c,
+                          preferred_element_type=jnp.float32)
+
+    with jax.named_scope("mla.attend"):
+        ctx = _split_k_attention(pairs, pos, cp.shape[2], cfg.attn_window,
+                                 (H,), r, score, value)
+    with jax.named_scope("mla.absorb"):
+        return jnp.einsum("shr,rhv->shv", ctx.astype(q_nope.dtype), p["w_uv"])
 
 
 def _mla_prefill_attention(q: Array, cp: Array, pp: Array, li, table_row: Array,

@@ -220,13 +220,14 @@ def test_routing_counters_count_held_and_all_pairs():
 
 
 # sha256 of the jaxpr text of the dense family's paged programs at this
-# size, as traced before the expert family entered them (jax 0.9.0): the
-# dense path must trace to the same computation.  A deliberate change to
-# the dense paged programs updates these.
+# size (jax 0.9.0): prefill as traced before the expert family entered it,
+# decode since its attention reads only the live pages (the split-K loop at
+# ``DECODE_PAGE_BLOCK`` 128).  The dense path must trace to the same
+# computation; a deliberate change to the dense paged programs updates these.
 DENSE_JAXPRS = {
-    ("tiny", "decode"): "918c61352fbe71f623d20a6bfb28db045c888c3064053af40f6fbda15df53647",
+    ("tiny", "decode"): "a02a5dc3c6fd4cd5288d3b9e3239db094407e1f69c72160906dd8350a99718f6",
     ("tiny", "prefill"): "cd99c11c514c703fa25a77cdb0f7836aa5f1f088b91e1832cb3c91fd5cdfb6aa",
-    ("window", "decode"): "c505fcaab198fdcf91a987e6c002ba7377e577463a6f2c1d500c26c972665d2d",
+    ("window", "decode"): "d5785a7f1c604180d520cdf869d1e059df260743b90df2c4c9024843984a7141",
     ("window", "prefill"): "5b5fb5c4f95f82893ce2b8dce87e98186c221e9fec774b0366b5e599d7b71e1f",
 }
 
